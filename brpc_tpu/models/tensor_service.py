@@ -26,7 +26,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from brpc_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from brpc_tpu.ops.fused_update import (fused_momentum_update,
@@ -100,6 +100,27 @@ def flagship_entry(batch: int = 64, din: int = 256, dh: int = 512,
 # Sharded step: client (dp) × shard (tp) mesh + ring relay.
 # ---------------------------------------------------------------------------
 
+@jax.custom_vjp
+def _merge_partials(y_part: jax.Array) -> jax.Array:
+    """psum over SHARD forward, identity backward. Every shard computes
+    the same loss from the merged y, so the cotangent arriving here is
+    already the full one on each shard; psum's own transpose under
+    check_vma=False would sum it again and scale every upstream gradient
+    by the shard count."""
+    return jax.lax.psum(y_part, SHARD_AXIS)
+
+
+def _merge_partials_fwd(y_part):
+    return _merge_partials(y_part), None
+
+
+def _merge_partials_bwd(_, ct):
+    return (ct,)
+
+
+_merge_partials.defvjp(_merge_partials_fwd, _merge_partials_bwd)
+
+
 def make_sharded_train_step(mesh: Mesh):
     """The full distributed step, shard_map'ed over (client, shard).
 
@@ -122,7 +143,7 @@ def make_sharded_train_step(mesh: Mesh):
                              w2.astype(jnp.bfloat16),
                              preferred_element_type=jnp.float32)
             # Merge the partition partials (PartitionChannel fan-in).
-            y = jax.lax.psum(y_part, SHARD_AXIS) + b2
+            y = _merge_partials(y_part) + b2
             return jnp.mean(jnp.square(y - target)), y
 
         (loss, y), grads = jax.value_and_grad(

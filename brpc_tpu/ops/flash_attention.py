@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from brpc_tpu.utils import compat
-
 _NEG = -1e30  # "never attended" sentinel: finite so corrections stay 0, not NaN
 
 
@@ -160,7 +158,7 @@ def flash_attention_carry(q, k, v, m, l, acc, offsets, *, causal: bool = False,
         ],
         # bh and q-blocks are independent; only the k-block walk carries
         # the online-softmax state (the revisited out blocks).
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(offsets.astype(jnp.int32), q, k, v, m, l, acc)

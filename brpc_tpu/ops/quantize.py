@@ -14,8 +14,9 @@ fine for kernel-parity tests, far too slow for traffic).
 
 Tiling: int8/fp8 VMEM tiles need >= 32 sublanes (pallas_guide.md dtype
 table), so codes reshape to (nblocks, block) and tile as (32, block)
-with the matching (32, 1) scale column; block must be a lane multiple
-(128) for the compiled path — the codec default of 256 is.
+with the matching (32, 1) scale column. The tile spans the whole block
+axis, so any block compiles (a block that is not a lane multiple of 128
+pads inside VMEM); the codec default of 256 is two lanes exactly.
 """
 
 from __future__ import annotations
@@ -41,11 +42,10 @@ def dequantize_blocks(q, scales, *, block: int, n: int, shape,
 
     ``q`` is an int8 or float8_e4m3fn device array of the raw wire codes;
     ``interpret=None`` auto-selects like fused_momentum_update: compiled
-    Pallas on TPU, plain jnp elsewhere (and whenever ``block`` is not a
-    lane multiple).
+    Pallas on TPU, plain jnp elsewhere.
     """
     if interpret is None:
-        if jax.default_backend() != "tpu" or block % 128 != 0:
+        if jax.default_backend() != "tpu":
             return dequantize_reference(q, scales, block=block, n=n,
                                         shape=shape)
         interpret = False

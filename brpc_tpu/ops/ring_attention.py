@@ -28,8 +28,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from brpc_tpu.utils.compat import shard_map
 
 from brpc_tpu.ops.flash_attention import (flash_attention_carry,
                                           flash_finalize, flash_init)
@@ -71,8 +71,6 @@ def ring_attention(mesh: Mesh, axis: str = SHARD_AXIS, *,
 
         # Unrolled: n is the (small, static) mesh axis size, and unrolling
         # lets XLA overlap each ICI hop with the previous fold's matmuls.
-        # (A lax.scan here also trips an XLA SPMD PartitionId lowering bug
-        # on older jax when combined with ppermute + interpreted pallas.)
         k_blk, v_blk = k, v
         for t in range(n - 1):
             # Rotate first; XLA overlaps the ICI hop with the matmuls.

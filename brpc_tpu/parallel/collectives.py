@@ -24,7 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from brpc_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from brpc_tpu.parallel.mesh import CLIENT_AXIS, SHARD_AXIS

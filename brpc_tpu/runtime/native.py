@@ -21,8 +21,10 @@ import atexit
 import contextlib
 import ctypes
 import errno
+import fcntl
 import os
 import re
+import shutil
 import subprocess
 import weakref
 from typing import Callable, Optional, Tuple
@@ -94,7 +96,8 @@ def parse_moved(text: str) -> Optional[str]:
     return m.group(1) if m else None
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_LIB_PATH = os.path.join(_REPO, "native", "build", "libbrpc_tpu.so")
+_BUILD_DIR = os.path.join(_REPO, "native", "build")
+_LIB_PATH = os.path.join(_BUILD_DIR, "libbrpc_tpu.so")
 
 _HANDLER_CB = ctypes.CFUNCTYPE(
     None,
@@ -163,40 +166,71 @@ def _teardown_native_handles() -> None:
             pass
 
 
-def _build_native() -> None:
-    # Build-on-demand runs at the first lib() call, before any server,
-    # channel, or fiber exists — there is no handler path to stall yet.
-    build = os.path.join(_REPO, "native", "build")
-    subprocess.run(  # tpulint: allow(py-blocking)
-        ["cmake", "-S", "native", "-B", build, "-G", "Ninja",
-         "-DCMAKE_BUILD_TYPE=RelWithDebInfo"],
-        cwd=_REPO, check=True, capture_output=True)
-    subprocess.run(  # tpulint: allow(py-blocking)
-        ["cmake", "--build", build], cwd=_REPO, check=True,
-        capture_output=True)
+def _configured_here() -> bool:
+    """True when native/build was configured from THIS checkout. A build
+    tree copied from another path carries a CMakeCache.txt that points at
+    that path's sources: cmake refuses to reuse it, and trusting its .so
+    would load code this checkout never compiled."""
+    cache = os.path.join(_BUILD_DIR, "CMakeCache.txt")
+    try:
+        with open(cache, encoding="utf-8", errors="replace") as fh:
+            for line in fh:
+                if line.startswith("CMAKE_CACHEFILE_DIR:"):
+                    where = line.split("=", 1)[1].strip()
+                    return os.path.realpath(where) == os.path.realpath(
+                        _BUILD_DIR)
+    except OSError:
+        pass
+    return False
+
+
+def _have_toolchain() -> bool:
+    return shutil.which("cmake") is not None and shutil.which("ninja") is not None
+
+
+def build(target: Optional[str] = None) -> str:
+    """Build native/build from this checkout's own sources, incrementally;
+    returns the library path. A build tree configured elsewhere (or not at
+    all) is replaced by a fresh configure first. ``target`` limits the
+    build to one cmake target (``"brpc_tpu"``: the library alone).
+    Without cmake and ninja it raises and leaves native/build untouched."""
+    if not _have_toolchain():
+        raise RuntimeError("building native/ needs cmake and ninja on PATH")
+    # Runs before any server, channel, or fiber exists — there is no
+    # handler path to stall yet. The file lock serializes processes that
+    # build at once (test workers, a bench and its children): the second
+    # one finds the tree configured and its build is a no-op.
+    with open(os.path.join(_REPO, "native", ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)  # tpulint: allow(py-blocking)
+        if not _configured_here():
+            shutil.rmtree(_BUILD_DIR, ignore_errors=True)
+            subprocess.run(  # tpulint: allow(py-blocking)
+                ["cmake", "-S", "native", "-B", _BUILD_DIR, "-G", "Ninja",
+                 "-DCMAKE_BUILD_TYPE=RelWithDebInfo"],
+                cwd=_REPO, check=True, capture_output=True)
+        cmd = ["cmake", "--build", _BUILD_DIR]
+        if target is not None:
+            cmd += ["--target", target]
+        subprocess.run(  # tpulint: allow(py-blocking)
+            cmd, cwd=_REPO, check=True, capture_output=True)
+    return _LIB_PATH
 
 
 def lib() -> ctypes.CDLL:
-    """Loads (building on demand) the native library."""
+    """Loads the native library, building it first when this checkout has
+    no build of its own. Entry points that must run this checkout's
+    current sources call ``build()`` themselves (an incremental build is
+    not free, and concurrent test workers must not race one build tree).
+    A prebuilt library from another path still loads where there is no
+    toolchain to rebuild it."""
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB_PATH):
-        _build_native()
+    have_lib = os.path.exists(_LIB_PATH)
+    if not (have_lib and _configured_here()) and (
+            _have_toolchain() or not have_lib):
+        build()
     L = ctypes.CDLL(_LIB_PATH)
-    if not hasattr(L, "tbrpc_registry_install"):
-        # Stale build from before the current bindings: the handler ABI
-        # carries extra out-params now, so using it would marshal garbage
-        # (not just miss symbols). Rebuild — and verify the reload took:
-        # if the stale mapping was already dlopen'd, glibc hands the same
-        # handle back and only a fresh process can pick up the new build.
-        _build_native()
-        L = ctypes.CDLL(_LIB_PATH)
-        if not hasattr(L, "tbrpc_registry_install"):
-            raise RuntimeError(
-                "libbrpc_tpu.so was built before the current bindings and "
-                "the stale mapping is already loaded in this process; the "
-                "rebuild is on disk — restart Python to pick it up")
     L.tbrpc_server_create.restype = ctypes.c_void_p
     L.tbrpc_server_start.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
     L.tbrpc_server_start_tls.argtypes = [
@@ -261,7 +295,7 @@ def lib() -> ctypes.CDLL:
         ctypes.c_uint64, ctypes.c_char_p, ctypes.c_size_t]
     # Hang forensics: callable from ANY plain pthread even when every
     # fiber worker is parked (how the socket-id-0 credit-leak wedge was
-    # root-caused — see PERF.md round 6).
+    # root-caused in an old host run, records deleted in PR 21).
     L.tbrpc_debug_dump_fibers.restype = ctypes.c_int64
     L.tbrpc_debug_dump_fibers.argtypes = [ctypes.c_char_p, ctypes.c_size_t]
     L.tbrpc_debug_dump_ici.restype = ctypes.c_int64

@@ -530,7 +530,7 @@ class ParameterServer:
         groups pulls and this handler concatenates the encoded tensors
         into ONE attachment behind a JSON manifest. (Raw pulls stay
         per-tensor: at 4 logical bytes per wire byte they are transport-
-        bound, and grouping would buy nothing — measured in PERF r9.)
+        bound, and grouping would buy nothing — measured in an old host run, records deleted in PR 21.)
 
         Per-name misses ride the manifest as {"name", "code", "error"}
         entries instead of failing the group: mid-reshard a single moved
@@ -1336,7 +1336,7 @@ class ParameterClient:
     # ---- pipelined multi-tensor hot path (PipelineWindow) ----
     # The serial pull/push above pay one full round-trip per tensor: a
     # model with N parameter tensors pays N x the ~260us 1MB latency
-    # floor (PERF.md round 3) although the transport sustains ~3x the
+    # floor (old run, records deleted in PR 21) although the transport sustains ~3x the
     # single-stream throughput at conc=8 (BENCH r05). These keep a
     # bounded window of RPCs in flight instead, so N tensors cost ~1
     # round-trip plus N wire times.
@@ -1358,7 +1358,7 @@ class ParameterClient:
         ``PullQ`` in groups of ``group`` tensors per RPC — the codec cuts
         each tensor ~4x, which leaves the per-RPC fixed cost dominating a
         per-tensor stream, so grouping is where the second half of the
-        effective-bandwidth win comes from (PERF round 9).
+        effective-bandwidth win comes from (old host run, records deleted in PR 21).
         """
         from brpc_tpu.runtime.tensor import (_decode_meta_ex, _metrics,
                                              _stage, consume_pull_reply)
@@ -1620,7 +1620,7 @@ class ParameterClient:
         error feedback) into groups of ``group`` per PushQ RPC — the
         codec cuts each ~4x, which leaves the per-RPC fixed cost
         dominating a per-tensor stream, the same second lever PullQ is
-        on the read side (PERF round 9). Per-name results ride the
+        on the read side (old host run, records deleted in PR 21). Per-name results ride the
         response manifest; a moved/undecodable name raises
         :class:`PartialPushError` with its groupmates' confirmed
         versions in ``applied``.

@@ -62,7 +62,7 @@ namespace contention_internal {
 
 std::atomic<bool> g_enabled{false};
 
-void Record(int64_t wait_us) {
+void Record(int64_t wait_ns) {
   if (!collector().Admit()) return;
   void* pcs[24];
   const size_t n = self_stack(pcs, 24);
@@ -71,7 +71,7 @@ void Record(int64_t wait_us) {
   // first return address (out of Record) already lands in the CONTENDED
   // CALL SITE itself.
   std::vector<void*> stack(pcs, pcs + n);
-  collector().Add(stack, wait_us);
+  collector().Add(stack, wait_ns);
 }
 
 }  // namespace contention_internal
@@ -99,9 +99,11 @@ std::string contention_report(size_t topn) {
   size_t shown = 0;
   for (const auto& e : entries) {
     if (shown++ >= topn) break;
-    snprintf(line, sizeof(line), "-- waited %lldus total over %lld hit(s):\n",
-             static_cast<long long>(e.total),
-             static_cast<long long>(e.count));
+    // Totals under a microsecond render in ns rather than as "0us".
+    const bool sub_us = e.total < 1000;
+    snprintf(line, sizeof(line), "-- waited %lld%s total over %lld hit(s):\n",
+             static_cast<long long>(sub_us ? e.total : e.total / 1000),
+             sub_us ? "ns" : "us", static_cast<long long>(e.count));
     out += line;
     for (void* pc : e.stack) {
       out += "    ";

@@ -14,10 +14,11 @@ namespace tbthread {
 
 namespace contention_internal {
 extern std::atomic<bool> g_enabled;
-// Slow-path callback: wait_us spent blocked before acquiring. Captures the
+// Slow-path callback: wait_ns spent blocked before acquiring (ns, so a
+// sub-microsecond wait still counts). Captures the
 // caller's stack (exact fiber bounds when on a fiber) under the collector's
 // speed limit.
-void Record(int64_t wait_us);
+void Record(int64_t wait_ns);
 }  // namespace contention_internal
 
 inline bool contention_profiling_enabled() {

@@ -34,7 +34,7 @@ class FiberMutex {
       return;
     }
     const bool profile = contention_profiling_enabled();
-    const int64_t t0 = profile ? tbutil::monotonic_time_us() : 0;
+    const int64_t t0 = profile ? tbutil::monotonic_time_ns() : 0;
     // Canonical contended loop (reference bthread/mutex.cpp
     // mutex_lock_contended): exchange(2) returning 0 means WE acquired —
     // the word stays 2, so our unlock wakes (possibly spuriously, which
@@ -51,7 +51,7 @@ class FiberMutex {
       butex_wait(_b, 2, nullptr);
     }
     if (profile) {
-      contention_internal::Record(tbutil::monotonic_time_us() - t0);
+      contention_internal::Record(tbutil::monotonic_time_ns() - t0);
     }
   }
 

@@ -15,6 +15,7 @@
 
 #include <algorithm>
 #include <atomic>
+#include <cstddef>
 #include <cstdlib>
 #include <cstring>
 #include <map>
@@ -319,55 +320,102 @@ std::string HeapProfiler::FlatText(size_t topn) {
 // funnels through these once libbrpc_tpu is linked; cost while not
 // profiling is a single relaxed load. malloc/free stay untouched (IOBuf's
 // block allocator reports via RecordAlloc/RecordFree instead).
-void* operator new(size_t size) {
-  void* p = malloc(size);
+//
+// They keep libstdc++'s contract exactly, because they also serve every
+// library loaded after this one (libstdc++ binds its own new/delete to the
+// first definition in its lookup scope): size 0 is a valid request, an
+// alignment below sizeof(void*) is legal (posix_memalign would reject it
+// with EINVAL), and a null result runs the new_handler before giving up.
+namespace {
+
+inline void* raw_alloc(size_t size, size_t align) {
+  if (size == 0) size = 1;
+  if (align < sizeof(void*)) align = sizeof(void*);
+  for (;;) {
+    void* p = nullptr;
+    if (align <= alignof(std::max_align_t)) {
+      p = malloc(size);
+    } else if (posix_memalign(&p, align, size) != 0) {
+      p = nullptr;
+    }
+    if (p != nullptr) return p;
+    std::new_handler handler = std::get_new_handler();
+    if (handler == nullptr) return nullptr;
+    handler();
+  }
+}
+
+inline void* throwing_alloc(size_t size, size_t align) {
+  void* p = raw_alloc(size, align);
   if (p == nullptr) throw std::bad_alloc();
-  tbutil::on_alloc(p, size, __builtin_return_address(0),
-                   __builtin_frame_address(0));
+  return p;
+}
+
+inline void* nothrow_alloc(size_t size, size_t align) noexcept {
+  try {
+    return raw_alloc(size, align);
+  } catch (...) {  // a new_handler may throw bad_alloc
+    return nullptr;
+  }
+}
+
+}  // namespace
+
+#define TB_SAMPLE(p, size)                                           \
+  tbutil::on_alloc(p, size, __builtin_return_address(0),             \
+                   __builtin_frame_address(0))
+
+void* operator new(size_t size) {
+  void* p = throwing_alloc(size, 0);
+  TB_SAMPLE(p, size);
   return p;
 }
 
 void* operator new[](size_t size) {
-  void* p = malloc(size);
-  if (p == nullptr) throw std::bad_alloc();
-  tbutil::on_alloc(p, size, __builtin_return_address(0),
-                   __builtin_frame_address(0));
+  void* p = throwing_alloc(size, 0);
+  TB_SAMPLE(p, size);
   return p;
 }
 
 void* operator new(size_t size, const std::nothrow_t&) noexcept {
-  void* p = malloc(size);
-  tbutil::on_alloc(p, size, __builtin_return_address(0),
-                   __builtin_frame_address(0));
+  void* p = nothrow_alloc(size, 0);
+  TB_SAMPLE(p, size);
   return p;
 }
 
 void* operator new[](size_t size, const std::nothrow_t&) noexcept {
-  void* p = malloc(size);
-  tbutil::on_alloc(p, size, __builtin_return_address(0),
-                   __builtin_frame_address(0));
+  void* p = nothrow_alloc(size, 0);
+  TB_SAMPLE(p, size);
   return p;
 }
 
 void* operator new(size_t size, std::align_val_t al) {
-  void* p = nullptr;
-  if (posix_memalign(&p, static_cast<size_t>(al), size) != 0) {
-    throw std::bad_alloc();
-  }
-  tbutil::on_alloc(p, size, __builtin_return_address(0),
-                   __builtin_frame_address(0));
+  void* p = throwing_alloc(size, static_cast<size_t>(al));
+  TB_SAMPLE(p, size);
   return p;
 }
 
 void* operator new[](size_t size, std::align_val_t al) {
-  void* p = nullptr;
-  if (posix_memalign(&p, static_cast<size_t>(al), size) != 0) {
-    throw std::bad_alloc();
-  }
-  tbutil::on_alloc(p, size, __builtin_return_address(0),
-                   __builtin_frame_address(0));
+  void* p = throwing_alloc(size, static_cast<size_t>(al));
+  TB_SAMPLE(p, size);
   return p;
 }
+
+void* operator new(size_t size, std::align_val_t al,
+                   const std::nothrow_t&) noexcept {
+  void* p = nothrow_alloc(size, static_cast<size_t>(al));
+  TB_SAMPLE(p, size);
+  return p;
+}
+
+void* operator new[](size_t size, std::align_val_t al,
+                     const std::nothrow_t&) noexcept {
+  void* p = nothrow_alloc(size, static_cast<size_t>(al));
+  TB_SAMPLE(p, size);
+  return p;
+}
+
+#undef TB_SAMPLE
 
 void operator delete(void* p) noexcept { tbutil::on_free(p); free(p); }
 void operator delete[](void* p) noexcept { tbutil::on_free(p); free(p); }
@@ -394,6 +442,16 @@ void operator delete(void* p, size_t, std::align_val_t) noexcept {
   free(p);
 }
 void operator delete[](void* p, size_t, std::align_val_t) noexcept {
+  tbutil::on_free(p);
+  free(p);
+}
+void operator delete(void* p, std::align_val_t,
+                     const std::nothrow_t&) noexcept {
+  tbutil::on_free(p);
+  free(p);
+}
+void operator delete[](void* p, std::align_val_t,
+                       const std::nothrow_t&) noexcept {
   tbutil::on_free(p);
   free(p);
 }

@@ -7,20 +7,12 @@ entry point, mirroring how the reference's test/ drives all layers.
 import glob
 import os
 import subprocess
+import sys
 
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BUILD = os.path.join(REPO, "native", "build")
-
-
-def _ensure_built():
-    subprocess.run(
-        ["cmake", "-S", "native", "-B", BUILD, "-G", "Ninja",
-         "-DCMAKE_BUILD_TYPE=RelWithDebInfo"],
-        cwd=REPO, check=True, capture_output=True)
-    subprocess.run(["cmake", "--build", BUILD], cwd=REPO, check=True,
-                   capture_output=True)
 
 
 def _test_binaries():
@@ -39,7 +31,9 @@ def _built():
     # A prebuilt tree on a toolchain-less machine is still runnable;
     # only (re)build when the tools to do so exist.
     if _toolchain_available():
-        _ensure_built()
+        from brpc_tpu.runtime import native
+
+        native.build()
 
 
 @pytest.mark.parametrize("binary", _test_binaries(),
@@ -51,3 +45,19 @@ def test_native(binary):
                           timeout=300)
     assert proc.returncode == 0, (
         f"{os.path.basename(binary)} failed:\n{proc.stdout}\n{proc.stderr}")
+
+
+def test_native_lib_loads_before_jax():
+    """The library's global operator new/delete serve every library loaded
+    after it (libstdc++ binds to the first definition in its scope), so
+    loading it BEFORE jax must leave jax working — the order every bench
+    child, fleet member and chip_smoke.py uses. It once aborted here with
+    std::bad_alloc."""
+    code = ("from brpc_tpu.runtime import native\n"
+            "native.lib()\n"
+            "import jax.numpy as jnp\n"
+            "assert float(jnp.arange(4.0).sum()) == 6.0\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]

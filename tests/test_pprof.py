@@ -170,8 +170,10 @@ def test_contention_page_format_under_induced_contention(busy_server):
                  body.splitlines()[0])
     assert m, f"unexpected /contention header: {body.splitlines()[0]!r}"
     assert int(m.group(1)) > 0, body
-    # Every stack block reports its total wait and hit count...
-    waits = re.findall(r"-- waited (\d+)us total over (\d+) hit\(s\):", body)
+    # Every stack block reports its total wait (us, or ns under a
+    # microsecond) and hit count...
+    waits = re.findall(r"-- waited (\d+)(?:us|ns) total over (\d+) hit\(s\):",
+                       body)
     assert waits and all(int(w) > 0 and int(h) > 0 for w, h in waits), body
     # ...and symbolized frames (dladdr resolves exported symbols; the
     # anonymous-namespace contender itself renders as a raw address, but

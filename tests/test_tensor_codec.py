@@ -190,19 +190,26 @@ def test_dequantize_reference_matches_numpy():
                                rtol=1e-6, atol=1e-7)
 
 
-def test_pallas_dequant_kernel_parity_interpret():
+@pytest.mark.parametrize("name,n,block", [
+    ("int8", 40 * 256, 256),
+    ("int8", 1_000_003, 100),
+    ("fp8e4m3", 1_000_003, 100),
+], ids=["int8-b256", "int8-odd-b100", "fp8-odd-b100"])
+def test_pallas_dequant_kernel_parity_interpret(name, n, block):
     """The compiled-path kernel evaluated tile-by-tile through the
     interpreter == the jnp reference (same discipline as
-    fused_momentum_update's kernel test)."""
-    a = _rng(7).normal(size=(40 * 256,)).astype(np.float32)
-    enc = codec.encode(a, "int8", min_bytes=0)
-    meta = {"dtype": "<f4", "shape": [a.size], "codec": "int8",
-            "block": 256}
+    fused_momentum_update's kernel test) — including a peer's block that
+    is not a multiple of 128 with a ragged tail, which the chip runs
+    through the kernel too."""
+    a = _rng(7).normal(size=(n,)).astype(np.float32)
+    enc = codec.encode(a, name, block=block, min_bytes=0)
+    meta = {"dtype": "<f4", "shape": [a.size], "codec": name,
+            "block": block}
     q, scales = codec.split_wire(meta, enc.wire)
-    got = dequantize_blocks(jnp.asarray(q), jnp.asarray(scales), block=256,
+    got = dequantize_blocks(jnp.asarray(q), jnp.asarray(scales), block=block,
                             n=a.size, shape=(a.size,), interpret=True)
     ref = dequantize_reference(jnp.asarray(q), jnp.asarray(scales),
-                               block=256, n=a.size, shape=(a.size,))
+                               block=block, n=a.size, shape=(a.size,))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
