@@ -17,11 +17,21 @@ What this module adds on top of the native machinery:
     /rpcz next to the native legs;
   * stage(name) / annotate(text): stage timings ("device_put=812us")
     attached to whatever span is ACTIVE — a server handler annotates its
-    server span, a trace_span() body annotates itself;
+    server span, a trace_span() body annotates itself. Each stage is also
+    a "brpc.<name>" jax.profiler.TraceAnnotation, so it lands on the
+    profiler's host plane on the same clock as the device's ops;
+  * the GIL waits on both sides of the ctypes boundary: serving() opens a
+    Python callback (gil_enter on the server span, from the callback
+    pool's stamp; the pool annotates pool_wait itself), gil_returned()
+    follows a traced tbrpc_call_tensor (gil_return on the client's span);
   * rpcz control and span dumps without HTTP round-trips.
 
-Everything no-ops cheaply while rpcz is off (the rpcz_enabled flag,
-flippable live at /flags/rpcz_enabled?setvalue=1 or rpcz_enable()).
+While rpcz is off (the rpcz_enabled flag, flippable live at
+/flags/rpcz_enabled?setvalue=1 or rpcz_enable()) the per-call entry
+points — stage, annotate, trace_span, serving, gil_returned — make no
+foreign call at all: "on" is one read of the flag's storage in place.
+While it is on, their native calls go through native.pylib(), which
+keeps the GIL across them instead of handing it off.
 """
 
 from __future__ import annotations
@@ -29,10 +39,15 @@ from __future__ import annotations
 import contextlib
 import ctypes
 import json
+import sys
 import time
-from typing import Iterator, List, Optional, Tuple
+from typing import Iterator, List, Tuple
 
 from brpc_tpu.runtime import native
+
+_OFF = contextlib.nullcontext()
+_flag = None                # the rpcz_enabled flag's storage, read in place
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once JAX is in
 
 
 class RpczDisabled(RuntimeError):
@@ -57,7 +72,13 @@ def rpcz_enable(on: bool = True) -> None:
 
 
 def rpcz_enabled() -> bool:
-    return native.lib().tbrpc_rpcz_enabled() != 0
+    """One read of the native flag's int64 storage: no foreign call (a
+    CDLL call would release the GIL and take it back)."""
+    global _flag
+    if _flag is None:
+        _flag = ctypes.c_int64.from_address(
+            native.lib().tbrpc_rpcz_enabled_flag())
+    return _flag.value != 0
 
 
 def rpcz_set_sample_1_in_n(n: int) -> None:
@@ -78,38 +99,98 @@ def current_trace() -> Tuple[int, int]:
     """The active (trace_id, span_id) on this thread/fiber; (0, 0) = none."""
     t = ctypes.c_uint64()
     s = ctypes.c_uint64()
-    native.lib().tbrpc_trace_current(ctypes.byref(t), ctypes.byref(s))
+    native.pylib().tbrpc_trace_current(ctypes.byref(t), ctypes.byref(s))
     return t.value, s.value
 
 
 def set_trace(trace_id: int, span_id: int) -> None:
-    native.lib().tbrpc_trace_set(trace_id, span_id)
+    native.pylib().tbrpc_trace_set(trace_id, span_id)
 
 
 def clear_trace() -> None:
-    native.lib().tbrpc_trace_clear()
+    native.pylib().tbrpc_trace_clear()
 
 
 def new_id() -> int:
-    return native.lib().tbrpc_trace_new_id()
+    return native.pylib().tbrpc_trace_new_id()
 
 
 def annotate(text: str) -> None:
-    """Attach free-form text to the active span (no-op without one)."""
-    native.lib().tbrpc_span_annotate(text.encode("utf-8", errors="replace"))
+    """Attach free-form text to the active span (no-op without one, and
+    while rpcz is off)."""
+    if rpcz_enabled():
+        native.pylib().tbrpc_span_annotate(
+            text.encode("utf-8", errors="replace"))
 
 
-@contextlib.contextmanager
-def stage(name: str) -> Iterator[None]:
+def _profiler_event(name: str):
+    """A jax.profiler.TraceAnnotation named ``name`` where this process
+    runs JAX (a process without it gets a no-op: tracing never imports
+    JAX itself)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return _OFF
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name)
+
+
+class _Stage:
+    __slots__ = ("name", "_event", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> None:
+        self._event = _profiler_event("brpc." + self.name)
+        self._event.__enter__()
+        self._t0 = time.monotonic()
+
+    def __exit__(self, *exc) -> None:
+        us = int((time.monotonic() - self._t0) * 1e6)
+        self._event.__exit__(*exc)
+        annotate(f"{self.name}={us}us")
+
+
+def stage(name: str):
     """Time the body and attach "name=<us>us" to the ACTIVE span — the
-    per-stage breakdown (rpc / arena-map / device_put / fused-update) the
-    tensor path reports."""
-    t0 = time.monotonic()
-    try:
-        yield
-    finally:
-        us = int((time.monotonic() - t0) * 1e6)
-        annotate(f"{name}={us}us")
+    per-stage breakdown (rpc / arena_stage / device_put / handler /
+    respond_stage / update_dispatch) the tensor path reports — inside a
+    "brpc.<name>" profiler annotation. While rpcz is off: a shared no-op."""
+    return _Stage(name) if rpcz_enabled() else _OFF
+
+
+def _since_us(stamp_us: int) -> int:
+    """Microseconds from a native stamp (gettimeofday) to now, on the same
+    clock (CLOCK_REALTIME); a stamp of 0 means "not traced"."""
+    return max(0, time.time_ns() // 1000 - stamp_us)
+
+
+def serving():
+    """The first line of a Python service callback: annotate the server
+    span with ``gil_enter`` (the callback pool's call stamp to here: the
+    GIL wait and ctypes' argument conversion) and open a "brpc.serve"
+    profiler annotation around the callback. While rpcz is off, or for a
+    request without a server span: a shared no-op."""
+    if not rpcz_enabled():
+        return _OFF
+    stamp = native.pylib().tbrpc_callback_called_us()
+    if stamp == 0:
+        return _OFF
+    annotate(f"gil_enter={_since_us(stamp)}us")
+    return _profiler_event("brpc.serve")
+
+
+def gil_returned() -> None:
+    """The first line after a tbrpc_call_tensor: annotate the caller's
+    active span with ``gil_return``, the time from the native return to
+    this thread holding the GIL again."""
+    if rpcz_enabled():
+        stamp = native.pylib().tbrpc_call_returned_us()
+        if stamp:
+            annotate(f"gil_return={_since_us(stamp)}us")
 
 
 class SpanHandle:
@@ -137,12 +218,12 @@ def trace_span(name: str, *, server_side: bool = False
     spans, server handlers) or starts a fresh trace (a client root); the
     body runs with THIS span as the context, so downstream tbrpc calls —
     and nested trace_spans — parent here. Recorded via tbrpc_span_emit on
-    exit; while rpcz is off the body runs untraced at ~zero cost.
+    exit; while rpcz is off the body runs untraced, with no foreign call.
     """
-    L = native.lib()
     if not rpcz_enabled():
         yield SpanHandle(0, 0)
         return
+    L = native.pylib()
     parent_trace, parent_span = current_trace()
     # Head sampling: a span with NO surrounding context would start a new
     # root trace — consult the 1-in-N gate exactly like the native client
@@ -183,17 +264,7 @@ def dump_rpcz(trace_id: int = 0) -> List[dict]:
     """
     from brpc_tpu.observability.metrics import _snapshot_buf
 
-    L = native.lib()
-    if L.tbrpc_rpcz_enabled() == 0:
+    if not rpcz_enabled():
         raise RpczDisabled("local")
-    raw = _snapshot_buf(L.tbrpc_rpcz_dump_json, trace_id)
+    raw = _snapshot_buf(native.lib().tbrpc_rpcz_dump_json, trace_id)
     return json.loads(raw.decode(errors="replace")) if raw else []
-
-
-def find_trace(service_method: str) -> Optional[str]:
-    """The trace_id (hex) of the most recent span for `service_method`;
-    None if not collected. Convenience for tests and tooling."""
-    for span in dump_rpcz():
-        if span["service_method"] == service_method:
-            return span["trace_id"]
-    return None

@@ -320,23 +320,13 @@ def lib() -> ctypes.CDLL:
     L.tbrpc_debug_hold_workers.argtypes = [ctypes.c_int, ctypes.c_int64]
     L.tbrpc_debug_induce_contention.restype = ctypes.c_int64
     L.tbrpc_debug_induce_contention.argtypes = [ctypes.c_int, ctypes.c_int64]
+    # rpcz control; the per-call tracing calls bind on pylib() below.
     L.tbrpc_rpcz_enabled.restype = ctypes.c_int
     L.tbrpc_rpcz_set_enabled.argtypes = [ctypes.c_int]
-    # Head sampling for Python-created ROOT spans (trace_span): combines
-    # rpcz_enabled with the reloadable rpcz_sample_1_in_n flag.
-    L.tbrpc_rpcz_sample_root.restype = ctypes.c_int
-    L.tbrpc_rpcz_sample_root.argtypes = []
     L.tbrpc_rpcz_sample_1_in_n.restype = ctypes.c_int
     L.tbrpc_rpcz_sample_1_in_n.argtypes = []
-    L.tbrpc_trace_new_id.restype = ctypes.c_uint64
-    L.tbrpc_trace_current.argtypes = [
-        ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_uint64)]
-    L.tbrpc_trace_set.argtypes = [ctypes.c_uint64, ctypes.c_uint64]
-    L.tbrpc_span_annotate.argtypes = [ctypes.c_char_p]
-    L.tbrpc_span_emit.argtypes = [
-        ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64, ctypes.c_int,
-        ctypes.c_int64, ctypes.c_int64, ctypes.c_int, ctypes.c_char_p]
-    L.tbrpc_now_us.restype = ctypes.c_int64
+    L.tbrpc_rpcz_enabled_flag.restype = ctypes.c_void_p
+    L.tbrpc_rpcz_enabled_flag.argtypes = []
     L.tbrpc_flag_set.restype = ctypes.c_int
     L.tbrpc_flag_set.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
     # Fleet: the process-global service registry (brpc_tpu/fleet rides it
@@ -402,6 +392,50 @@ def lib() -> ctypes.CDLL:
     _lib = L
     atexit.register(_teardown_native_handles)
     return L
+
+
+_pylib = None
+
+
+def pylib() -> ctypes.PyDLL:
+    """The same library through a handle whose calls KEEP the GIL, for the
+    short rpcz calls on the per-call path (annotations, span emission,
+    trace context, clock stamps). A CDLL call releases the GIL and takes
+    it back, and under several Python threads every release is a chance
+    to wait for it; none of these calls blocks or calls back into Python,
+    so holding the GIL across them is cheaper than handing it off."""
+    global _pylib
+    if _pylib is not None:
+        return _pylib
+    lib()  # built and loaded first: this handle shares its globals
+    P = ctypes.PyDLL(_LIB_PATH)
+    # Head sampling for Python-created ROOT spans (trace_span): combines
+    # rpcz_enabled with the reloadable rpcz_sample_1_in_n flag.
+    P.tbrpc_rpcz_sample_root.restype = ctypes.c_int
+    P.tbrpc_rpcz_sample_root.argtypes = []
+    P.tbrpc_trace_new_id.restype = ctypes.c_uint64
+    P.tbrpc_trace_new_id.argtypes = []
+    P.tbrpc_trace_current.restype = None
+    P.tbrpc_trace_current.argtypes = [
+        ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_uint64)]
+    P.tbrpc_trace_set.restype = None
+    P.tbrpc_trace_set.argtypes = [ctypes.c_uint64, ctypes.c_uint64]
+    P.tbrpc_trace_clear.restype = None
+    P.tbrpc_trace_clear.argtypes = []
+    P.tbrpc_span_annotate.restype = None
+    P.tbrpc_span_annotate.argtypes = [ctypes.c_char_p]
+    P.tbrpc_span_emit.restype = None
+    P.tbrpc_span_emit.argtypes = [
+        ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64, ctypes.c_int,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int, ctypes.c_char_p]
+    P.tbrpc_now_us.restype = ctypes.c_int64
+    P.tbrpc_now_us.argtypes = []
+    P.tbrpc_callback_called_us.restype = ctypes.c_int64
+    P.tbrpc_callback_called_us.argtypes = []
+    P.tbrpc_call_returned_us.restype = ctypes.c_int64
+    P.tbrpc_call_returned_us.argtypes = []
+    _pylib = P
+    return P
 
 
 @contextlib.contextmanager
@@ -558,11 +592,17 @@ class Server:
             cap = int(need) + 1
 
     def add_service(self, name: str, handler: Handler) -> None:
+        from brpc_tpu.observability import tracing
+
         L = self._L
 
-        def trampoline(ctx, method, req, req_len, att, att_len,
-                       resp, resp_len, resp_att, resp_att_len, error_code,
-                       err_text, err_text_cap):
+        def trampoline(*args):
+            with tracing.serving():  # gil_enter; no-op while rpcz is off
+                serve(*args)
+
+        def serve(ctx, method, req, req_len, att, att_len,
+                  resp, resp_len, resp_att, resp_att_len, error_code,
+                  err_text, err_text_cap):
             try:
                 request = ctypes.string_at(req, req_len) if req_len else b""
                 attachment = ctypes.string_at(att, att_len) if att_len else b""

@@ -884,7 +884,9 @@ class ParameterServer:
                         f"parameter {name} migrating in; retry shortly")
                 p = self._params[name]
                 m = self._momenta[name]
-            with tracing.stage("fused_update"):
+            # Times the update's dispatch on the device path (its device
+            # time is in the profiler trace), the whole update on the host.
+            with tracing.stage("update_dispatch"):
                 if self._on_device:
                     # Dispatch-only: blocking on device completion here
                     # would serialize every update behind its device

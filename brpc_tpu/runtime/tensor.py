@@ -34,6 +34,7 @@ from typing import Callable, Optional, Tuple
 
 import numpy as np
 
+from brpc_tpu.observability import tracing
 from brpc_tpu.runtime import native
 from brpc_tpu.runtime.native import RpcError, fill_err_text, lib
 
@@ -214,10 +215,7 @@ def _metrics():
     return _metrics_cache
 
 
-def _stage(name):
-    from brpc_tpu.observability import tracing
-
-    return tracing.stage(name)
+_stage = tracing.stage
 
 
 # ---- pipeline in-flight gauge ----
@@ -1094,6 +1092,7 @@ class TensorChannel:
             ctypes.byref(resp), ctypes.byref(resp_len), ctypes.byref(view),
             ctypes.byref(ratt), ctypes.byref(ratt_len), ctypes.byref(copied),
             errbuf, len(errbuf))
+        tracing.gil_returned()
         if rc != 0:
             raise RpcError(rc, errbuf.value.decode(errors="replace"))
         try:
@@ -1266,9 +1265,15 @@ def add_tensor_service(server: native.Server, name: str,
     L = _bind_tensor_api(lib())
     srv_arena = arena if arena is not None else TensorArena(256 << 20)
 
-    def trampoline(ctx, method, req, req_len, att, att_len,
-                   resp, resp_len, resp_arena, resp_off, resp_att_len,
-                   resp_autofree, error_code, err_text, err_text_cap):
+    def trampoline(*args):
+        # The server span's gil_enter, and "brpc.serve" on the profiler's
+        # host plane around the whole callback (no-op while rpcz is off).
+        with tracing.serving():
+            serve(*args)
+
+    def serve(ctx, method, req, req_len, att, att_len,
+              resp, resp_len, resp_arena, resp_off, resp_att_len,
+              resp_autofree, error_code, err_text, err_text_cap):
         t0 = time.monotonic()
         try:
             request = ctypes.string_at(req, req_len) if req_len else b""
@@ -1310,35 +1315,41 @@ def add_tensor_service(server: native.Server, name: str,
                                 E_UNDECODABLE,
                                 f"undecodable tensor payload "
                                 f"(meta={meta!r}): {e}") from e
-            r, out_arr = handler(method.decode(), request, att_view)
-            if isinstance(out_arr, WireTensor):
-                # Pre-encoded response (quantized pull path): stage the
-                # wire bytes as-is, send the handler's exact header.
-                if out_arr.placed is not None:
-                    off, nbytes = out_arr.placed
-                else:
-                    off, nbytes, _ = srv_arena.place(out_arr.data)
-                r = out_arr.header + r
-                if nbytes:
-                    resp_arena[0] = srv_arena.handle
-                    resp_off[0] = off
-                    resp_att_len[0] = nbytes
-                    resp_autofree[0] = 1
-            elif out_arr is not None:
-                off, nbytes, host = srv_arena.place(out_arr)
-                r = _encode_meta(host) + r
-                if nbytes:
-                    resp_arena[0] = srv_arena.handle
-                    resp_off[0] = off
-                    resp_att_len[0] = nbytes
-                    # Autofree: the C side frees AFTER taking the response
-                    # ref, so the range returns once the client releases.
-                    resp_autofree[0] = 1
-            if r:
-                buf = L.tbrpc_alloc(len(r))
-                ctypes.memmove(buf, r, len(r))
-                resp[0] = buf
-                resp_len[0] = len(r)
+            with tracing.stage("handler"):
+                r, out_arr = handler(method.decode(), request, att_view)
+            # The response's staging: a pull's D2H and arena copy, its
+            # meta header and the payload's copy into native memory.
+            with tracing.stage("respond_stage"):
+                if isinstance(out_arr, WireTensor):
+                    # Pre-encoded response (quantized pull path): stage
+                    # the wire bytes as-is, send the handler's exact
+                    # header.
+                    if out_arr.placed is not None:
+                        off, nbytes = out_arr.placed
+                    else:
+                        off, nbytes, _ = srv_arena.place(out_arr.data)
+                    r = out_arr.header + r
+                    if nbytes:
+                        resp_arena[0] = srv_arena.handle
+                        resp_off[0] = off
+                        resp_att_len[0] = nbytes
+                        resp_autofree[0] = 1
+                elif out_arr is not None:
+                    off, nbytes, host = srv_arena.place(out_arr)
+                    r = _encode_meta(host) + r
+                    if nbytes:
+                        resp_arena[0] = srv_arena.handle
+                        resp_off[0] = off
+                        resp_att_len[0] = nbytes
+                        # Autofree: the C side frees AFTER taking the
+                        # response ref, so the range returns once the
+                        # client releases.
+                        resp_autofree[0] = 1
+                if r:
+                    buf = L.tbrpc_alloc(len(r))
+                    ctypes.memmove(buf, r, len(r))
+                    resp[0] = buf
+                    resp_len[0] = len(r)
         except RpcError as e:
             error_code[0] = e.code if e.code != 0 \
                 else native.TRPC_EINTERNAL

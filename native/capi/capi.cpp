@@ -203,6 +203,42 @@ class PyCallbackPool {
   int64_t _outstanding = 0;
 };
 
+// Per-thread stamps of traced crossings of the ctypes boundary
+// (tbrpc_callback_called_us, tbrpc_call_returned_us): the Python side
+// subtracts them from its own first timestamp to time its GIL wait.
+thread_local int64_t t_callback_called_us = 0;
+thread_local int64_t t_call_returned_us = 0;
+
+// rpcz timing of one Python callback of a request with a server span:
+// pool_wait runs from the service fiber's enqueue to the pool pthread
+// calling the callback; that call stamp stays on the pool thread for the
+// callback's gil_enter. Nothing is stamped for an untraced request.
+class CallbackTiming {
+ public:
+  explicit CallbackTiming(uint64_t span_id)
+      : _span_id(span_id),
+        _queued_us(span_id != 0 ? tbutil::gettimeofday_us() : 0) {}
+
+  // On the pool pthread, just before the callback.
+  void Calling() {
+    t_callback_called_us =
+        _span_id != 0 ? (_called_us = tbutil::gettimeofday_us()) : 0;
+  }
+
+  // On the service fiber once the job ran, before done->Run() records the
+  // span.
+  void Annotate() const {
+    if (_called_us == 0) return;
+    AnnotateSpan(_span_id,
+                 "pool_wait=" + std::to_string(_called_us - _queued_us) + "us");
+  }
+
+ private:
+  const uint64_t _span_id;
+  const int64_t _queued_us;
+  int64_t _called_us = 0;
+};
+
 // The Controller of the RPC a Python handler is CURRENTLY serving, on the
 // callback-pool pthread running it. Lets in-handler capi entry points that
 // need the Controller (tbrpc_stream_accept: the response must carry the
@@ -256,6 +292,7 @@ class CallbackService : public Service {
     err_text[0] = '\0';
     const TraceContext trace_ctx = current_trace_context();
     const QosContext qos_ctx = current_qos_context();
+    CallbackTiming timing(trace_ctx.span_id);
     const bool ran = PyCallbackPool::instance().Run([&] {
       // The pool thread inherits the server span: nested calls the Python
       // handler issues parent there, keeping the trace linked. Same for
@@ -264,10 +301,12 @@ class CallbackService : public Service {
       ScopedTraceContext scope(trace_ctx.trace_id, trace_ctx.span_id);
       ScopedQosContext qos_scope(qos_ctx);
       ScopedHandlerController hc(cntl);  // tbrpc_stream_accept's doorway
+      timing.Calling();
       _cb(_ctx, method.c_str(), req.data(), req.size(), att.data(),
           att.size(), &resp, &resp_len, &resp_att, &resp_att_len,
           &error_code, err_text, sizeof(err_text));
     }, qos_ctx.priority);
+    timing.Annotate();
     if (!ran) {
       // A pool shed is an overload answer like any gate shed: count it
       // and carry a retry-after hint (drain time of a saturated pool is
@@ -637,6 +676,15 @@ int tbrpc_call_tensor(void* channel, const char* service_method,
                       size_t* resp_len, void** view, const void** ratt_ptr,
                       size_t* ratt_len, int* ratt_copied, char* errbuf,
                       size_t errbuf_len) {
+  // Declared first, so destroyed last: stamps the very return to a traced
+  // caller, after every local's teardown.
+  struct ReturnStamp {
+    const bool traced =
+        rpcz_enabled() && current_trace_context().span_id != 0;
+    ~ReturnStamp() {
+      t_call_returned_us = traced ? tbutil::gettimeofday_us() : 0;
+    }
+  } return_stamp;
   auto* box = static_cast<ChannelBox*>(channel);
   Controller cntl;
   tbutil::IOBuf request, response;
@@ -1019,14 +1067,17 @@ void TensorCallbackService::CallMethod(const std::string& method,
   err_text[0] = '\0';
   const TraceContext trace_ctx = current_trace_context();
   const QosContext qos_ctx = current_qos_context();
+  CallbackTiming timing(trace_ctx.span_id);
   const bool ran = PyCallbackPool::instance().Run([&] {
     ScopedTraceContext scope(trace_ctx.trace_id, trace_ctx.span_id);
     ScopedQosContext qos_scope(qos_ctx);
     ScopedHandlerController hc(cntl);  // tbrpc_stream_accept's doorway
+    timing.Calling();
     _cb(_ctx, method.c_str(), req.data(), req.size(), att_ptr, att_len,
         &resp, &resp_len, &resp_arena, &resp_att_off, &resp_att_len,
         &resp_att_autofree, &error_code, err_text, sizeof(err_text));
   }, qos_ctx.priority);
+  timing.Annotate();
   if (!ran) {
     error_code = TRPC_ELIMIT;
     GlobalRpcMetrics::instance().shed_total << 1;
@@ -1429,6 +1480,12 @@ void tbrpc_span_emit(uint64_t trace_id, uint64_t span_id,
 }
 
 int64_t tbrpc_now_us(void) { return tbutil::gettimeofday_us(); }
+
+const void* tbrpc_rpcz_enabled_flag(void) { return rpcz_enabled_flag(); }
+
+int64_t tbrpc_callback_called_us(void) { return t_callback_called_us; }
+
+int64_t tbrpc_call_returned_us(void) { return t_call_returned_us; }
 
 int tbrpc_flag_set(const char* name, const char* value) {
   if (name == nullptr || value == nullptr) return -1;

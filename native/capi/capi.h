@@ -316,6 +316,19 @@ void tbrpc_span_emit(uint64_t trace_id, uint64_t span_id,
                      const char* name);
 // Wall-clock microseconds on the same clock spans use (gettimeofday).
 int64_t tbrpc_now_us(void);
+// Address of the rpcz_enabled flag's int64 storage, stable for the
+// process: a caller that decides "is rpcz on" on every call reads it in
+// place instead of calling tbrpc_rpcz_enabled.
+const void* tbrpc_rpcz_enabled_flag(void);
+// This thread's stamp (tbrpc_now_us clock) of the last traced crossing of
+// the ctypes boundary, 0 when that request or call was not traced:
+// - on a callback-pool pthread: when it called the Python callback of a
+//   request with a server span (the callback's first line times its GIL
+//   wait from it; the pool wait before it is annotated as pool_wait);
+// - on a caller's thread: when tbrpc_call_tensor returned to a caller
+//   with an active trace context.
+int64_t tbrpc_callback_called_us(void);
+int64_t tbrpc_call_returned_us(void);
 
 // Reloadable-flag access (the /flags page, from code): 0 ok, -1 on unknown
 // flag / parse error / validator veto.

@@ -28,6 +28,8 @@ bool rpcz_enabled() {
   return g_rpcz_enabled->load(std::memory_order_relaxed) != 0;
 }
 
+const std::atomic<int64_t>* rpcz_enabled_flag() { return g_rpcz_enabled; }
+
 int64_t rpcz_sample_1_in_n() {
   const int64_t n = g_rpcz_sample_1_in_n->load(std::memory_order_relaxed);
   return n >= 1 ? n : 1;

@@ -11,6 +11,7 @@
 // chain renders as one linked trace at /rpcz.
 #pragma once
 
+#include <atomic>
 #include <cstdint>
 #include <string>
 #include <vector>
@@ -52,6 +53,9 @@ class SpanStore {
 
 // True when spans should be collected (rpcz_enabled flag, hot-path cached).
 bool rpcz_enabled();
+// The flag's storage, stable for the process: callers that cannot afford a
+// function call per check (Python, through ctypes) read it in place.
+const std::atomic<int64_t>* rpcz_enabled_flag();
 
 // Head sampling for always-on production rpcz (rpcz_sample_1_in_n flag,
 // default 1 = every trace). Consulted ONLY where a NEW root trace would be

@@ -390,6 +390,161 @@ def test_param_server_metrics_recorded():
         assert name in prom, name
 
 
+# ---- the split of one call: stages on the server leg and the client ----
+
+SERVER_STAGES = ("pool_wait", "gil_enter", "handler", "respond_stage")
+
+
+def _stages(span):
+    out = {}
+    for a in span["annotations"]:
+        name, _, us = a.partition("=")
+        if ANN_RE.match(a):
+            out[name] = int(us[:-2])
+    return out
+
+
+def test_traced_pull_and_push_split_the_server_leg(obs):
+    """The server leg of a traced Pull and Push carries the callback
+    pool's wait, the GIL wait into the callback, the handler and the
+    response staging as sequential stages (summing to no more than the
+    leg), the Push its update_dispatch, and the client's root the GIL
+    wait after the call returned."""
+    import jax.numpy as jnp
+
+    from brpc_tpu.runtime.param_server import (ParameterClient,
+                                               ParameterServer)
+
+    ps = ParameterServer({"w": jnp.ones((32, 4), jnp.float32)}, lr=0.01)
+    port = ps.start()
+    client = ParameterClient(f"tpu://127.0.0.1:{port}")
+    client.meta()
+    obs.rpcz_enable()
+    try:
+        roots = {}
+        for kind in ("pull", "push"):
+            with obs.trace_span(f"split.{kind}") as root:
+                if kind == "pull":
+                    client.pull("w")
+                else:
+                    client.push_grad("w", jnp.ones((32, 4), jnp.float32))
+            roots[kind] = root.trace_id
+        for kind, trace_id in roots.items():
+            spans = obs.dump_rpcz(trace_id)
+            leg = next(s for s in spans if s["server_side"])
+            root = next(s for s in spans
+                        if s["service_method"] == f"split.{kind}")
+            stages = _stages(leg)
+            assert set(SERVER_STAGES) <= set(stages), leg["annotations"]
+            assert sum(stages[k] for k in SERVER_STAGES) <= \
+                leg["latency_us"], leg
+            if kind == "push":
+                assert "update_dispatch" in stages
+                assert stages["update_dispatch"] <= stages["handler"]
+            assert "gil_return" in _stages(root), root["annotations"]
+            assert "rpc" in _stages(root)
+            # the server leg lies inside the client's rpc stage
+            assert leg["latency_us"] <= _stages(root)["rpc"] + 1
+    finally:
+        obs.rpcz_enable(False)
+        client.close()
+        ps.stop()
+
+
+class _CountingLib:
+    """A library handle that counts every foreign call made through it."""
+
+    def __init__(self, real):
+        self._real = real
+        self.calls = []
+
+    def __getattr__(self, name):
+        fn = getattr(self._real, name)
+
+        def counted(*args):
+            self.calls.append(name)
+            return fn(*args)
+
+        return counted
+
+
+def test_tracing_off_makes_no_foreign_call(obs, monkeypatch):
+    """With rpcz off, the per-call tracing entry points decide from the
+    flag's storage alone: no call through either library handle."""
+    from brpc_tpu.observability import tracing
+    from brpc_tpu.runtime import native
+
+    obs.rpcz_enable(False)
+    assert not tracing.rpcz_enabled()  # binds the flag's storage
+    cdll = _CountingLib(native.lib())
+    pydll = _CountingLib(native.pylib())
+    monkeypatch.setattr(native, "_lib", cdll)
+    monkeypatch.setattr(native, "_pylib", pydll)
+    for _ in range(3):
+        with tracing.stage("off"):
+            pass
+        tracing.annotate("off=1us")
+        with tracing.trace_span("off") as h:
+            assert h.trace_id == 0
+        with tracing.serving():
+            pass
+        tracing.gil_returned()
+    assert cdll.calls == [] and pydll.calls == []
+    # the same handles do carry the calls once rpcz is on
+    monkeypatch.undo()
+    obs.rpcz_enable()
+    try:
+        monkeypatch.setattr(native, "_lib", cdll)
+        monkeypatch.setattr(native, "_pylib", pydll)
+        with tracing.trace_span("on"):
+            with tracing.stage("on"):
+                pass
+        assert cdll.calls == []
+        assert {"tbrpc_span_annotate", "tbrpc_span_emit",
+                "tbrpc_now_us"} <= set(pydll.calls)
+    finally:
+        monkeypatch.undo()
+        obs.rpcz_enable(False)
+
+
+def test_stages_land_on_the_profiler_host_plane(obs, tmp_path):
+    """While rpcz is on, every stage is also a brpc.<stage> annotation on
+    the profiler's host plane, and the tensor service's callback a
+    brpc.serve around them."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from brpc_tpu.runtime.param_server import (ParameterClient,
+                                               ParameterServer)
+
+    ps = ParameterServer({"w": jnp.ones((32, 4), jnp.float32)}, lr=0.01)
+    port = ps.start()
+    client = ParameterClient(f"tpu://127.0.0.1:{port}")
+    client.meta()
+    obs.rpcz_enable()
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.trace_span("plane.pull"):
+                client.pull("w")
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs.rpcz_enable(False)
+        client.close()
+        ps.stop()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for p in ProfileData.from_file(path).planes
+             if p.name.startswith("/host:") for line in p.lines
+             for e in line.events}
+    assert {"brpc.rpc", "brpc.serve", "brpc.handler",
+            "brpc.respond_stage"} <= names, sorted(
+                n for n in names if n.startswith("brpc."))
+
+
 # ---- /tensorz ----
 
 def test_tensorz_page_shows_arena_occupancy():
