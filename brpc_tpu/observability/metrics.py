@@ -42,13 +42,14 @@ class Counter:
 
     def __init__(self, name: str):
         self._L = native.lib()
+        self._P = native.pylib()  # the write keeps the GIL: see pylib()
         self._h = self._L.tbrpc_var_adder_create(name.encode())
         if not self._h:
             raise ValueError(f"metric name already registered: {name!r}")
         self.name = name
 
     def add(self, delta: int = 1) -> None:
-        self._L.tbrpc_var_adder_add(self._h, delta)
+        self._P.tbrpc_var_adder_add(self._h, delta)
 
     def value(self) -> int:
         return self._L.tbrpc_var_adder_value(self._h)
@@ -61,13 +62,14 @@ class LatencyRecorder:
 
     def __init__(self, prefix: str):
         self._L = native.lib()
+        self._P = native.pylib()  # the write keeps the GIL: see pylib()
         self._h = self._L.tbrpc_var_latency_create(prefix.encode())
         if not self._h:
             raise ValueError(f"metric prefix already registered: {prefix!r}")
         self.prefix = prefix
 
     def record_us(self, latency_us: int) -> None:
-        self._L.tbrpc_var_latency_record(self._h, max(0, int(latency_us)))
+        self._P.tbrpc_var_latency_record(self._h, max(0, int(latency_us)))
 
     def record_s(self, seconds: float) -> None:
         self.record_us(int(seconds * 1e6))

@@ -399,16 +399,50 @@ _pylib = None
 
 def pylib() -> ctypes.PyDLL:
     """The same library through a handle whose calls KEEP the GIL, for the
-    short rpcz calls on the per-call path (annotations, span emission,
-    trace context, clock stamps). A CDLL call releases the GIL and takes
-    it back, and under several Python threads every release is a chance
-    to wait for it; none of these calls blocks or calls back into Python,
-    so holding the GIL across them is cheaper than handing it off."""
+    short calls of the per-call path. A CDLL call releases the GIL and
+    takes it back, and under several Python threads every release is a
+    chance to wait for it; holding the GIL across a short call is cheaper
+    than handing it off.
+
+    Admission rule: a function binds here only if it (1) never blocks —
+    no condition-variable or butex wait, no TX-credit wait, no I/O; (2)
+    never calls back into Python, directly or through a path that waits
+    for the callback pool; and (3) takes only locks that no native path
+    holds across a call into Python (callback-pool trampolines,
+    PassiveGauge callbacks under the tbvar registry lock, done-callbacks).
+    A call that breaks any of these would hold the GIL while waiting for
+    a thread that needs it. Admitted: the rpcz calls (annotations, span
+    emission, trace context, clock stamps); the recorder and counter
+    writes (per-thread agents); the QoS slot (thread-local); free and
+    copy (tbrpc_free/dup: free, malloc + memcpy); arena alloc/free (the
+    arena mutex, held only for its free-list update). Kept on ``lib()``: every
+    call that waits for an RPC or for the arena, server and channel
+    lifecycle, and ``tbrpc_view_free``, whose release may write a frame
+    on the socket inline and, if that write fails, finish an async RPC
+    whose done-callback waits for the callback pool."""
     global _pylib
     if _pylib is not None:
         return _pylib
     lib()  # built and loaded first: this handle shares its globals
     P = ctypes.PyDLL(_LIB_PATH)
+    # Per-call writes of the data path (audited: see the rule above).
+    P.tbrpc_var_adder_add.restype = None
+    P.tbrpc_var_adder_add.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+    P.tbrpc_var_latency_record.restype = None
+    P.tbrpc_var_latency_record.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+    P.tbrpc_qos_set.restype = ctypes.c_int
+    P.tbrpc_qos_set.argtypes = [ctypes.c_int, ctypes.c_char_p]
+    P.tbrpc_qos_get.restype = ctypes.c_int64
+    P.tbrpc_qos_get.argtypes = [
+        ctypes.POINTER(ctypes.c_int), ctypes.c_char_p, ctypes.c_size_t]
+    P.tbrpc_free.restype = None
+    P.tbrpc_free.argtypes = [ctypes.c_void_p]
+    P.tbrpc_dup.restype = ctypes.c_void_p
+    P.tbrpc_dup.argtypes = [ctypes.c_void_p, ctypes.c_size_t]
+    P.tbrpc_arena_alloc.restype = ctypes.c_int64
+    P.tbrpc_arena_alloc.argtypes = [ctypes.c_void_p, ctypes.c_size_t]
+    P.tbrpc_arena_free.restype = ctypes.c_int
+    P.tbrpc_arena_free.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
     # Head sampling for Python-created ROOT spans (trace_span): combines
     # rpcz_enabled with the reloadable rpcz_sample_1_in_n flag.
     P.tbrpc_rpcz_sample_root.restype = ctypes.c_int
@@ -453,17 +487,17 @@ def qos(priority: int = PRIORITY_NORMAL, tenant: str = ""):
     DEADLINE lives in the same slot but is untouched by set/restore, so
     nested-call clamping survives any qos() nesting. Raises ValueError
     for tenants over the 256-byte wire cap."""
-    L = lib()
+    P = pylib()  # the slot is thread-local: nothing here can wait
     prev_prio = ctypes.c_int()
     prev_tenant = ctypes.create_string_buffer(512)  # cap is 256
-    L.tbrpc_qos_get(ctypes.byref(prev_prio), prev_tenant, len(prev_tenant))
-    if L.tbrpc_qos_set(priority,
+    P.tbrpc_qos_get(ctypes.byref(prev_prio), prev_tenant, len(prev_tenant))
+    if P.tbrpc_qos_set(priority,
                        tenant.encode() if tenant else b"") != 0:
         raise ValueError(f"tenant id too long ({len(tenant)} bytes > 256)")
     try:
         yield
     finally:
-        L.tbrpc_qos_set(prev_prio.value, prev_tenant.value)
+        P.tbrpc_qos_set(prev_prio.value, prev_tenant.value)
 
 
 def deadline_remaining_ms() -> Optional[int]:

@@ -55,10 +55,6 @@ def _bind_tensor_api(L: ctypes.CDLL) -> ctypes.CDLL:
     L.tbrpc_arena_destroy.argtypes = [ctypes.c_void_p]
     L.tbrpc_arena_base.restype = ctypes.c_void_p
     L.tbrpc_arena_base.argtypes = [ctypes.c_void_p]
-    L.tbrpc_arena_alloc.restype = ctypes.c_int64
-    L.tbrpc_arena_alloc.argtypes = [ctypes.c_void_p, ctypes.c_size_t]
-    L.tbrpc_arena_free.restype = ctypes.c_int
-    L.tbrpc_arena_free.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
     L.tbrpc_arena_busy_bytes.restype = ctypes.c_int64
     L.tbrpc_arena_busy_bytes.argtypes = [ctypes.c_void_p]
     L.tbrpc_arenas_busy_bytes.restype = ctypes.c_int64
@@ -339,6 +335,7 @@ class TensorArena:
 
     def __init__(self, nbytes: int):
         self._L = _bind_tensor_api(lib())
+        self._P = native.pylib()  # alloc/free keep the GIL: see pylib()
         self._h = self._L.tbrpc_arena_create(nbytes)
         if not self._h:
             raise MemoryError(f"arena create({nbytes}) failed")
@@ -353,13 +350,13 @@ class TensorArena:
     def alloc(self, nbytes: int) -> int:
         if not self._h:
             raise RuntimeError("arena is closed")
-        off = self._L.tbrpc_arena_alloc(self._h, nbytes)
+        off = self._P.tbrpc_arena_alloc(self._h, nbytes)
         if off < 0:
             raise MemoryError(f"arena alloc({nbytes}) failed (fragmented?)")
         return off
 
     def free(self, off: int) -> None:
-        self._L.tbrpc_arena_free(self._h, off)
+        self._P.tbrpc_arena_free(self._h, off)
 
     def view(self, off: int, nbytes: int) -> np.ndarray:
         """A uint8 numpy view of arena pages — writes here ARE the staging
@@ -635,10 +632,14 @@ class TensorView:
     the connection's RX segment). ``release()`` is what sends the release
     frame back and lets the sender reuse the range — call it (or use as a
     context manager) as soon as the bytes are consumed (e.g. after
-    jax.device_put returns)."""
+    jax.device_put returns).
 
-    def __init__(self, L, view_handle, ptr, nbytes, copied: bool):
+    ``L`` releases the view (``tbrpc_view_free`` hands the GIL off: the
+    release may write on the socket), ``P`` frees a flattened copy."""
+
+    def __init__(self, L, P, view_handle, ptr, nbytes, copied: bool):
         self._L = L
+        self._P = P
         self._view = view_handle
         self._ptr = ptr
         self._copied = copied
@@ -661,7 +662,7 @@ class TensorView:
             self._L.tbrpc_view_free(self._view)
             self._view = None
         elif self._copied and self._ptr:
-            self._L.tbrpc_free(self._ptr)
+            self._P.tbrpc_free(self._ptr)
         self._ptr = None
 
     def __enter__(self):
@@ -807,8 +808,9 @@ class TensorFuture:
     release the response exactly once, whichever way the race goes.
     """
 
-    def __init__(self, L, handle, service_method, done_cb=None):
+    def __init__(self, L, P, handle, service_method, done_cb=None):
         self._L = L
+        self._P = P
         self._h = handle
         self._method = service_method
         self._cb = done_cb  # the ctypes trampoline must outlive the RPC
@@ -862,8 +864,8 @@ class TensorFuture:
                 self._payload = (ctypes.string_at(resp, resp_len.value)
                                  if resp_len.value else b"")
             finally:
-                L.tbrpc_free(resp)
-            self._view = TensorView(L, view.value, ratt.value,
+                self._P.tbrpc_free(resp)
+            self._view = TensorView(L, self._P, view.value, ratt.value,
                                     ratt_len.value, bool(copied.value))
         self.close()  # ownership is out; the native box is spent
         return True
@@ -1061,6 +1063,7 @@ class TensorChannel:
     def __init__(self, addr: str, arena: Optional[TensorArena] = None,
                  timeout_ms: int = 20000, max_retry: int = 0):
         self._L = _bind_tensor_api(lib())
+        self._P = native.pylib()
         if not addr.startswith("tpu://") and "://" not in addr:
             addr = "tpu://" + addr
         self._h = self._L.tbrpc_channel_create(addr.encode(), timeout_ms,
@@ -1099,9 +1102,9 @@ class TensorChannel:
             payload = (ctypes.string_at(resp, resp_len.value)
                        if resp_len.value else b"")
         finally:
-            L.tbrpc_free(resp)
-        return payload, TensorView(L, view.value, ratt.value, ratt_len.value,
-                                   bool(copied.value))
+            self._P.tbrpc_free(resp)
+        return payload, TensorView(L, self._P, view.value, ratt.value,
+                                   ratt_len.value, bool(copied.value))
 
     def call_async(self, service_method: str, request: bytes = b"",
                    att_off: int = 0, att_len: int = 0,
@@ -1141,7 +1144,7 @@ class TensorChannel:
         if not h:
             raise RpcError(native.TRPC_EINTERNAL,
                            f"async submit of {service_method} failed")
-        return TensorFuture(L, h, service_method, done_cb=cb)
+        return TensorFuture(L, self._P, h, service_method, done_cb=cb)
 
     def call(self, service_method: str, array=None, request: bytes = b""
              ) -> Tuple[bytes, Optional[np.ndarray]]:
@@ -1263,6 +1266,7 @@ def add_tensor_service(server: native.Server, name: str,
     response tensors through the service's own arena (by-reference on the
     wire). Returns that arena."""
     L = _bind_tensor_api(lib())
+    P = native.pylib()
     srv_arena = arena if arena is not None else TensorArena(256 << 20)
 
     def trampoline(*args):
@@ -1346,9 +1350,9 @@ def add_tensor_service(server: native.Server, name: str,
                         # client releases.
                         resp_autofree[0] = 1
                 if r:
-                    buf = L.tbrpc_alloc(len(r))
-                    ctypes.memmove(buf, r, len(r))
-                    resp[0] = buf
+                    # One GIL-keeping malloc + copy (ctypes.memmove is a
+                    # CFUNCTYPE: it would hand the GIL off).
+                    resp[0] = P.tbrpc_dup(r, len(r))
                     resp_len[0] = len(r)
         except RpcError as e:
             error_code[0] = e.code if e.code != 0 \

@@ -487,6 +487,12 @@ void tbrpc_channel_destroy(void* channel) {
 void* tbrpc_alloc(size_t n) { return malloc(n); }
 void tbrpc_free(void* p) { free(p); }
 
+void* tbrpc_dup(const void* p, size_t n) {
+  void* out = malloc(n);
+  if (out != nullptr) memcpy(out, p, n);
+  return out;
+}
+
 // ---------------- TensorArena ----------------
 
 namespace {

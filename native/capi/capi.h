@@ -68,6 +68,9 @@ int tbrpc_call(void* channel, const char* service_method, const void* req,
 
 void* tbrpc_alloc(size_t n);
 void tbrpc_free(void* p);
+// A tbrpc_alloc'd copy of [p, p+n): malloc + memcpy in one call (a
+// handler's response bytes), freed with tbrpc_free.
+void* tbrpc_dup(const void* p, size_t n);
 
 // ---- TensorArena: registered transfer memory for tensor payloads ----
 // The RDMA-registration analog (reference rdma_helper.h:48): a shm-backed

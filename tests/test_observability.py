@@ -507,6 +507,164 @@ def test_tracing_off_makes_no_foreign_call(obs, monkeypatch):
         obs.rpcz_enable(False)
 
 
+# Foreign calls of one raw Pull and one raw Push of an 8 KB tensor with
+# rpcz off, client and server legs together, per handle. The CDLL handle
+# (which hands the GIL off) carries only the RPC itself and the view
+# release (its drop may write a release frame inline; see pylib()).
+CENSUS = {
+    "pull": {
+        "cdll": {"tbrpc_call_tensor": 1, "tbrpc_view_free": 1},
+        # client: QoS scope (get, set, restore), response free, the
+        # tensor_pull recorder and byte counter; server: the handler's
+        # recorder, the response's arena range and header copy, the
+        # trampoline's recorder.
+        "pydll": {"tbrpc_qos_get": 1, "tbrpc_qos_set": 2, "tbrpc_free": 1,
+                  "tbrpc_var_latency_record": 3, "tbrpc_var_adder_add": 1,
+                  "tbrpc_arena_alloc": 1, "tbrpc_dup": 1},
+    },
+    "push": {
+        "cdll": {"tbrpc_call_tensor": 1},
+        # client: QoS scope, the staged range's alloc and free, response
+        # free, recorder and counter; server: recorder and counter, the
+        # header copy, the trampoline's recorder.
+        "pydll": {"tbrpc_qos_get": 1, "tbrpc_qos_set": 2,
+                  "tbrpc_arena_alloc": 1, "tbrpc_arena_free": 1,
+                  "tbrpc_free": 1, "tbrpc_var_latency_record": 3,
+                  "tbrpc_var_adder_add": 2, "tbrpc_dup": 1},
+    },
+}
+
+
+def test_tensor_call_census_per_handle(obs, monkeypatch):
+    """Every short foreign call of a raw Pull and Push rides the handle
+    that keeps the GIL; the CDLL handle sees the RPC and the audited
+    exceptions only, per kind."""
+    from collections import Counter
+
+    import jax.numpy as jnp
+
+    from brpc_tpu.observability import metrics as obs_metrics
+    from brpc_tpu.runtime import native
+    from brpc_tpu.runtime.param_server import (ParameterClient,
+                                               ParameterServer)
+    from brpc_tpu.runtime.tensor import _bind_tensor_api
+
+    obs.rpcz_enable(False)
+    # Signatures bind on the real handle: set through the counting one
+    # they would land on throwaway wrappers.
+    _bind_tensor_api(native.lib())
+    cdll = _CountingLib(native.lib())
+    pydll = _CountingLib(native.pylib())
+    monkeypatch.setattr(native, "_lib", cdll)
+    monkeypatch.setattr(native, "_pylib", pydll)
+    # Built after the patch: the channel, arenas and trampoline capture
+    # the counting handles.
+    ps = ParameterServer({"w": jnp.ones((32, 64), jnp.float32)}, lr=0.01)
+    port = ps.start()
+    client = ParameterClient(f"tpu://127.0.0.1:{port}")
+    grad = jnp.ones((32, 64), jnp.float32)
+    try:
+        client.pull("w")  # negotiates QoS, creates every recorder
+        client.push_grad("w", grad)
+        # Recorders are process-wide and may predate the patch.
+        for series in list(obs_metrics._registry.values()):
+            for attr, handle in (("_L", cdll), ("_P", pydll)):
+                if hasattr(series, attr):
+                    monkeypatch.setattr(series, attr, handle)
+        census = {}
+        for kind in ("pull", "push"):
+            cdll.calls.clear()
+            pydll.calls.clear()
+            if kind == "pull":
+                client.pull("w")
+            else:
+                client.push_grad("w", grad)
+            census[kind] = {"cdll": dict(Counter(cdll.calls)),
+                            "pydll": dict(Counter(pydll.calls))}
+        assert census == CENSUS
+    finally:
+        client.close()
+        ps.stop()
+
+
+# The liveness drive, in a child process: a deadlock on the GIL freezes
+# every Python thread of its process, the test's own included, so only a
+# parent can see it and report it.
+_LIVENESS = """
+import json, sys, threading, time
+sys.path.insert(0, %r)
+import jax.numpy as jnp
+import brpc_tpu.observability as obs
+from brpc_tpu.runtime.param_server import ParameterClient, ParameterServer
+
+shapes = {"small": (32, 64), "large": (256, 256)}
+state = {"scrapes": 0}
+obs.gauge("liveness_test_scrapes", lambda: state["scrapes"])
+ps = ParameterServer({n: jnp.ones(s, jnp.float32) for n, s in shapes.items()},
+                     lr=0.01)
+port = ps.start()
+clients = [ParameterClient("tpu://127.0.0.1:%%d" %% port) for _ in range(4)]
+stop_at = time.monotonic() + 2.0
+calls = []
+
+def worker(client):
+    grads = {n: jnp.ones(s, jnp.float32) for n, s in shapes.items()}
+    n = 0
+    while time.monotonic() < stop_at:
+        for name, g in grads.items():
+            client.pull(name)
+            client.push_grad(name, g)
+            n += 2
+    calls.append(n)
+
+def scraper():
+    while time.monotonic() < stop_at:
+        text = obs.dump_vars()
+        assert "tensor_arena_busy_bytes" in text, text
+        assert "liveness_test_scrapes" in text, text
+        ps.arena.busy_bytes()
+        state["scrapes"] += 1
+
+threads = [threading.Thread(target=worker, args=(c,)) for c in clients]
+threads.append(threading.Thread(target=scraper))
+for t in threads:
+    t.start()
+for t in threads:
+    t.join()
+for c in clients:
+    c.close()
+ps.stop()
+print(json.dumps({"calls": calls, "scrapes": state["scrapes"]}), flush=True)
+"""
+
+
+def test_gil_keeping_calls_stay_live_under_scrapes():
+    """The hazard of holding the GIL across a foreign call is a deadlock
+    with a native path that waits for Python. Four threads pull and push
+    for about two seconds while another dumps /vars (Python gauges run
+    on the callback pool under the registry lock) and reads the arena
+    gauges; every thread must finish within 30 s."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    child = subprocess.Popen(  # tpulint: allow(py-blocking)
+        [sys.executable, "-c", _LIVENESS % root],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    try:
+        out, err = child.communicate(timeout=30)
+    except subprocess.TimeoutExpired:
+        child.kill()
+        child.communicate()
+        pytest.fail("pulls, pushes and scrapes deadlocked")
+    assert child.returncode == 0, err[-4000:]
+    got = json.loads(out.splitlines()[-1])
+    assert len(got["calls"]) == 4 and min(got["calls"]) > 0, got
+    assert got["scrapes"] > 0, got
+
+
 def test_stages_land_on_the_profiler_host_plane(obs, tmp_path):
     """While rpcz is on, every stage is also a brpc.<stage> annotation on
     the profiler's host plane, and the tensor service's callback a
