@@ -4,14 +4,15 @@
         --seconds <s> [--out FILE] [--rehearse]
 
 One process (the chip belongs to one), one short window per seed at the
-cell's own load and sizes, through the same harness as a run. For each
-seed it records the program's readings (``pull_gap``, ``state_gap``,
-``version_faults``, ``failed_calls``) and the control's: the bfloat16
-replay put in the program's place and compared by the same comparison
-(``control_pull_gap``, ``control_state_gap``), and the harness's verdict
-on those readings (``control_correct``, which has to be false). The lower reading of a
-limit is the largest the program gives over the seeds; the upper one the
-smallest the control gives. The benchmark's own runs never run this.
+cell's own load and sizes, through the same harness and driver as a run,
+asked for the control too. For each seed it records the driver's readings,
+each number the run compared (``checks``), the same numbers with the
+control in the program's place (the driver's ``control_checks``: for the
+parameter-server driver the bfloat16 replay) and the harness's verdict on
+each (``correct``, and ``control_correct``, which has to be false). The
+lower reading of a limit is the largest the program gives over the seeds
+(``max_<number>``); the upper one the smallest the control gives
+(``min_control_<number>``). The benchmark's own runs never run this.
 """
 
 from __future__ import annotations
@@ -26,17 +27,13 @@ import time
 # The sibling script: importing it puts the checkout's root on sys.path.
 from run import bootstrap  # noqa: E402
 
-READINGS = ("pull_gap", "state_gap", "version_faults")
-CONTROL = ("control_pull_gap", "control_state_gap")
-
 
 def summarize(rows):
     """Largest program reading and smallest control reading per number."""
     out = {}
-    for k in READINGS + ("failed_calls",):
-        out[f"max_{k}"] = max(r[k] for r in rows)
-    for k in CONTROL:
-        out[f"min_{k}"] = min(r[k] for r in rows)
+    for k in rows[0]["checks"]:
+        out[f"max_{k}"] = max(r["checks"][k] for r in rows)
+        out[f"min_control_{k}"] = min(r["control_checks"][k] for r in rows)
     out["all_correct"] = all(r["correct"] for r in rows)
     out["any_control_correct"] = any(r["control_correct"] for r in rows)
     return out
@@ -54,17 +51,15 @@ def calibrate(workload: str, seeds, seconds: float, rehearse: bool):
         if isinstance(out, int):
             raise SystemExit(out)
         line, run, readings = out
-        control = check.control_checks(readings, line["failed"],
-                                       run.cell.config["limits"])
+        control = readings.pop("control_checks")
         row = {"seed": seed, "correct": line["correct"],
                "control_correct": check.passed(control),
                "attempted": line["attempted"],
                "failed_calls": line["failed"],
-               "pulls_compared": readings["pulls_compared"],
-               "worst_pull": readings["worst_pull"],
-               "worst_state": readings["worst_state"],
-               "wall_s": time.time() - t0}
-        row.update({k: readings[k] for k in READINGS + CONTROL})
+               "wall_s": time.time() - t0,
+               "checks": {k: c["value"] for k, c in line["checks"].items()},
+               "control_checks": {k: c["value"] for k, c in control.items()}}
+        row.update(readings)
         rows.append(row)
         del out, line, run, readings
         gc.collect()

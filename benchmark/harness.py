@@ -1,18 +1,20 @@
 """One run of one cell, from the seeded data to the result's last line.
 
-Order (ISSUE 22): the native library is loaded before JAX (run.py), the
-platform is checked, the configuration's tensors are made on chip 0 from
-the seed, a ParameterServer on ``tpu://127.0.0.1:0`` and the mix's
-clients start in this process on the same chip (traffic crosses the
-host's shm loopback, not a link), every distinct shape is warmed up, the
-window runs, and the result is checked against the plain reference.
+What every cell shares is here, in this order: the native library is
+loaded before JAX (run.py), the platform and the chip count are checked,
+the peaks looked up, and the cell's driver (benchmark/drivers/, named by
+its mix) sets up, warms up and drives its window inside a ``Session``,
+which marks the set-up, switches tracing on, takes ``setup_s``, runs the
+compile watch and the profiler slice, and reads the rpcz spans and the
+memory peak over the cell's chips. The driver checks what the window
+produced against its plain reference; the harness reduces the trace,
+runs the metric readers and prints the verdict.
 """
 
 from __future__ import annotations
 
 import functools
 import json
-import math
 import os
 import resource
 import shutil
@@ -20,11 +22,11 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from benchmark import check, data, devtrace, spec, traffic
+from benchmark import check, devtrace, spec
 
 TINY = 64              # the rehearsal's cap on every dimension
 TRACE_LEAD_S = 1.0     # the traced slice starts this far into the window
@@ -44,6 +46,24 @@ class Run:
     trace: Optional[devtrace.Reduced] = None
     spans: Optional[List[dict]] = None
     window_compiles: int = 0     # programs traced or compiled in the window
+    extra: Dict[str, float] = field(default_factory=dict)  # driver's counts
+
+
+@dataclass
+class Outcome:
+    """What a driver's ``run`` hands back: the window's calls (kind,
+    tensor, version, t0, t1) and the bytes of each tensor they name, the
+    window's length, the calls that failed and why, each compared number
+    beside its limit, the readings they came from (with ``control``, the
+    control's too), and counts of its own for the readers (``Run.extra``)."""
+    nbytes: List[int]
+    calls: np.ndarray
+    window_s: float
+    failed: int
+    errors: List[str]
+    checks: Dict[str, Dict[str, float]]
+    readings: Dict[str, object]
+    extra: Dict[str, float] = field(default_factory=dict)
 
 
 def process_start_wall() -> float:
@@ -78,21 +98,6 @@ def host_summary(before: tuple, after: tuple, window_s: float) -> str:
     return (f"host: steal {100 * steal / max(total, 1):.2f}% of all CPUs, "
             f"process {cpu_s / window_s:.2f} cores, {nivcsw} involuntary "
             f"context switches")
-
-
-def rate_summary(calls: np.ndarray, nbytes, t_open: float,
-                 window_s: float) -> str:
-    """GB/s of pulls and of pushes, and the spread of GB/s over the
-    window's whole seconds: a slow run uniformly slow, or stalled."""
-    b = np.asarray(nbytes, np.float64)[calls[:, 1].astype(np.int64)]
-    kinds = calls[:, 0].astype(np.int64)
-    n_s = max(1, int(window_s))
-    sec = np.minimum((calls[:, 4] - t_open).astype(np.int64), n_s - 1)
-    per_s = np.bincount(sec, weights=b, minlength=n_s) / 1e9
-    pulls, pushes = (b[kinds == k].sum() / window_s / 1e9
-                     for k in (traffic.PULL, traffic.PUSH))
-    return (f"GB/s: pulls {pulls:.4f}, pushes {pushes:.4f}; per second min {per_s.min():.4f}, median "
-            f"{np.median(per_s):.4f}, max {per_s.max():.4f}")
 
 
 def _log(msg: str) -> None:
@@ -156,6 +161,75 @@ def watch() -> CompileWatch:
     return CompileWatch()
 
 
+class Session:
+    """The harness's side of one run, handed to the cell's driver."""
+
+    def __init__(self, args, t_proc: float, devices, marks: List[tuple]):
+        self.args = args
+        self.t_proc = t_proc
+        self.devices = devices       # the cell's chips: devices[:chips]
+        self.marks = marks
+        self.setup_s = 0.0
+        self.window_s = 0.0
+        self.window_compiles = 0
+        self.spans: Optional[List[dict]] = None
+        self.mem_peak = 0
+        self.logdir: Optional[str] = None
+
+    @staticmethod
+    def log(msg: str) -> None:
+        _log(msg)
+
+    def mark(self, name: str) -> None:
+        """The end of one step of the set-up, for the set-up's log line."""
+        self.marks.append((name, time.time()))
+
+    def window(self, body: Callable, describe: str,
+               detail: Callable[[], str]) -> Tuple[float, float]:
+        """The measured window. Switches tracing on (``--trace 1``), takes
+        ``setup_s``, runs ``body(seconds, during) -> (t_open, t_close)``
+        under the compile watch, where ``during(t_end)`` is the profiler
+        slice (None untraced) for the body to run while its load runs, then
+        collects the rpcz spans and the memory peak over the cell's chips.
+        ``describe`` and ``detail()`` go into the log. -> (t_open, t_close)"""
+        args = self.args
+        during = None
+        if args.trace:
+            from brpc_tpu.observability import tracing
+            from brpc_tpu.runtime import native
+
+            native.lib().tbrpc_flag_set(b"rpcz_max_spans",
+                                        str(RPCZ_SPANS).encode())
+            tracing.rpcz_set_sample_1_in_n(1)
+            tracing.rpcz_enable(True)
+            self.logdir = tempfile.mkdtemp(prefix="bench-trace-")
+            during = _profiler_slice(self.logdir)
+        self.setup_s = time.time() - self.t_proc
+        edges = [self.t_proc] + [t for _, t in self.marks]
+        _log(f"setup {self.setup_s:.3f} s (" + ", ".join(
+            f"{k} {b - a:.3f}" for (k, _), a, b in
+            zip(self.marks, edges, edges[1:])) + f"); window {args.seconds} "
+             f"s, {describe}")
+        compiles = watch()
+        compiles.start()
+        host0 = host_counters()
+        t_open, t_close = body(args.seconds, during)
+        host1 = host_counters()
+        compiles.on = False
+        self.window_compiles = sum(compiles.counts.values())
+        self.window_s = t_close - t_open
+        _log(f"window {self.window_s:.3f} s: {compiles.summary()}; "
+             f"{detail()}; " + host_summary(host0, host1, self.window_s))
+        if args.trace:
+            self.spans = [s for s in tracing.dump_rpcz()
+                          if str(s.get("service_method", "")).startswith(
+                              "bench.")]
+            tracing.rpcz_enable(False)
+        self.mem_peak = max(int((x.memory_stats() or {}).get(
+            "peak_bytes_in_use", 0)) for x in self.devices)
+        return t_open, t_close
+
+
 def run(args, t_proc: float, rehearse: bool = False) -> int:
     """One run: the result's last line on stdout, each compared number
     beside its limit as the last lines of stderr. -> exit code."""
@@ -172,17 +246,14 @@ def run(args, t_proc: float, rehearse: bool = False) -> int:
 
 
 def execute(args, t_proc: float, rehearse: bool = False,
-            control: bool = False):
+            control: bool = False, root: str = spec.ROOT):
     """Everything of a run but the printing. -> (line, Run, readings), or
-    an exit code when the platform cannot run the cell. ``control`` adds
-    the bfloat16 replay's readings (calibrate.py)."""
+    an exit code when the platform cannot run the cell. ``control`` asks
+    the driver for the control's readings too (calibrate.py); ``root`` is
+    the checkout whose BENCHMARK.json and benchmark/ name the cell."""
     import jax
 
-    from brpc_tpu.runtime.param_server import (ParameterClient,
-                                               ParameterServer)
-    from brpc_tpu.runtime.tensor import TensorArena
-
-    cell = spec.load_cell(args.workload)
+    cell = spec.load_cell(args.workload, root)
     marks = [("native", getattr(args, "t_native", t_proc))]
     devs = jax.devices()
     marks.append(("jax_init", time.time()))
@@ -196,122 +267,27 @@ def execute(args, t_proc: float, rehearse: bool = False,
                  f"{len(devs)}")
             return 2
     peaks = spec.peaks(d.device_kind, cell.root) if not rehearse else {}
-    cfg, mix = cell.config, cell.traffic
-    layout = spec.tensors(cfg, tiny=TINY if rehearse else None)
-    names = [n for n, _ in layout]
-    shapes = [s for _, s in layout]
-    nbytes = [4 * math.prod(s) for s in shapes]
-    opt, init = cfg["optimizer"], cfg["init"]
-    lr, beta = float(opt["lr"]), float(opt["momentum"])
+    session = Session(args, t_proc, devs[:cell.chips], marks)
+    out = cell.driver.run(cell, args, session, rehearse=rehearse,
+                          control=control)
+    correct = check.passed(out.checks)
 
-    gen = data.generator(shapes)
-    params = data.make(gen, args.seed, data.PARAMS, init["param_std"])
-    bases = data.make(gen, args.seed, data.GRADS, init["grad_std"])
-    marks.append(("data", time.time()))
-    n_workers = int(mix["workers"])
-    largest = max(nbytes)
-    server = ParameterServer(
-        dict(zip(names, params)), lr=lr, momentum=beta,
-        arena=TensorArena(2 * n_workers * largest + (256 << 20)))
-    del params
-    port = server.start("127.0.0.1:0")
-    addr = f"tpu://127.0.0.1:{port}"
-    clients = [ParameterClient(addr, arena=TensorArena(2 * largest
-                                                       + (64 << 20)))
-               for _ in range(n_workers)]
-    try:
-        for c in clients:
-            c.meta()
-        marks.append(("server", time.time()))
-        grad_fn = data.scaler()
-        workers = [traffic.Worker(i, clients[i], mix, names, nbytes, bases,
-                                  grad_fn, args.seed, traced=bool(args.trace))
-                   for i in range(n_workers)]
-        traffic.warm_up(workers[0], shapes)
-        marks.append(("warm_up", time.time()))
-        logdir = None
-        during = None
-        if args.trace:
-            from brpc_tpu.observability import tracing
-            from brpc_tpu.runtime import native
-
-            native.lib().tbrpc_flag_set(b"rpcz_max_spans",
-                                        str(RPCZ_SPANS).encode())
-            tracing.rpcz_set_sample_1_in_n(1)
-            tracing.rpcz_enable(True)
-            logdir = tempfile.mkdtemp(prefix="bench-trace-")
-            during = _profiler_slice(logdir)
-        setup_s = time.time() - t_proc
-        edges = [t_proc] + [t for _, t in marks]
-        _log(f"setup {setup_s:.3f} s (" + ", ".join(
-            f"{k} {b - a:.3f}" for (k, _), a, b in
-            zip(marks, edges, edges[1:])) + f"); window {args.seconds} s, "
-             f"{n_workers} workers, {len(names)} tensors")
-        compiles = watch()
-        compiles.start()
-        host0 = host_counters()
-        t_open, t_close = traffic.drive(workers, args.seconds, during)
-        host1 = host_counters()
-        compiles.on = False
-        window_s = t_close - t_open
-        _log(f"window {window_s:.3f} s: {compiles.summary()}; worker steps "
-             f"{[w.steps for w in workers]}, calls "
-             f"{[len(w.calls) for w in workers]}; "
-             + host_summary(host0, host1, window_s))
-        spans = None
-        if args.trace:
-            spans = [s for s in tracing.dump_rpcz()
-                     if str(s.get("service_method", "")).startswith(
-                         "bench.")]
-            tracing.rpcz_enable(False)
-        used = devs[:cell.chips]
-        mem_peak = max(int((x.memory_stats() or {}).get(
-            "peak_bytes_in_use", 0)) for x in used)
-        # The server's final state, through its own export (Handoff: the
-        # stacked [params, momenta] at the latest committed version).
-        finals = {}
-        for t, name in enumerate(names):
-            version, stacked = clients[0].handoff(name)
-            finals[t] = (int(version), stacked[0], stacked[1])
-    finally:
-        for c in clients:
-            c.close()
-        server.stop()
-    del server
-    calls = np.array([c for w in workers for c in w.calls],
-                     dtype=np.float64).reshape(-1, 5)
-    _log(rate_summary(calls, nbytes, t_open, window_s))
-    failed = sum(w.failed for w in workers)
-    for w in workers:
-        for e in w.errors:
-            _log(f"worker {w.index}: {e}")
-    t_ref = time.perf_counter()
-    p0s = data.make(gen, args.seed, data.PARAMS, init["param_std"])
-    pushes = [p for w in workers for p in w.pushes]
-    pulls = [p for w in workers for p in w.sampled()]
-    readings = check.compare(shapes, p0s, bases, pushes, pulls, finals,
-                             lr, beta, control=control)
-    compared = check.checks(readings, failed, cfg["limits"])
-    correct = check.passed(compared)
-    _log(f"check {time.perf_counter() - t_ref:.3f} s over "
-         f"{len(pushes)} pushes, {readings['pulls_compared']} pulls; "
-         f"worst pull {readings['worst_pull']}, worst state "
-         f"{readings['worst_state']}")
-
-    result = Run(cell=cell, nbytes=nbytes, calls=calls, window_s=window_s,
-                 setup_s=setup_s, peaks=peaks, spans=spans,
-                 window_compiles=sum(compiles.counts.values()))
+    result = Run(cell=cell, nbytes=out.nbytes, calls=out.calls,
+                 window_s=out.window_s, setup_s=session.setup_s, peaks=peaks,
+                 spans=session.spans, window_compiles=session.window_compiles,
+                 extra=dict(out.extra))
     device = {"platform": d.platform, "kind": d.device_kind,
-              "count": cell.chips, "memory_peak_bytes": mem_peak}
-    line = {"correct": bool(correct), "attempted": int(len(calls) + failed),
-            "failed": int(failed)}
+              "count": cell.chips, "memory_peak_bytes": session.mem_peak}
+    line = {"correct": bool(correct),
+            "attempted": int(len(out.calls) + out.failed),
+            "failed": int(out.failed)}
     breakdown = None
     if args.trace:
         try:
             result.trace = devtrace.reduce(devtrace.extract(
-                devtrace.xplane_file(logdir)))
+                devtrace.xplane_file(session.logdir)))
         finally:
-            shutil.rmtree(logdir, ignore_errors=True)
+            shutil.rmtree(session.logdir, ignore_errors=True)
         if result.trace is not None:
             device["busy_s"] = result.trace.busy_s
             device["window_s"] = result.trace.window_s
@@ -328,5 +304,5 @@ def execute(args, t_proc: float, rehearse: bool = False,
         line["breakdown"] = breakdown
     if rehearse:
         line["rehearsal"] = True
-    line["checks"] = compared
-    return line, result, readings
+    line["checks"] = out.checks
+    return line, result, out.readings

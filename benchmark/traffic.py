@@ -170,17 +170,21 @@ class Worker:
 
 
 def warm_up(worker: Worker, shapes: Sequence[tuple]) -> None:
-    """One pull and one push of the first tensor of each distinct shape:
-    every program the window runs (the gradient scale, the server's
-    update) compiles or loads from the cache here. The pushes are
-    recorded like the window's, so the check replays them too."""
+    """Each call of the worker's step, in order, on the first tensor of
+    each distinct shape: every program the window runs (the gradient
+    scale, the server's update) compiles or loads from the cache here, and
+    nothing the window does not run. The pushes are recorded like the
+    window's, so the check replays them too."""
     seen = set()
     for t, shape in enumerate(shapes):
         if shape in seen:
             continue
         seen.add(shape)
-        worker.pull(t)
-        worker.push(t, np.float32(1.0))
+        for op in worker.ops:
+            if op == PULL:
+                worker.pull(t)
+            else:
+                worker.push(t, np.float32(1.0))
     worker.calls.clear()
     worker.kept.clear()
     worker.newest_largest = None

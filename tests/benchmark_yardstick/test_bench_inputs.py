@@ -21,7 +21,7 @@ def test_the_traced_runs_inputs_are_unchanged():
     assert (harness.TRACE_LEAD_S, harness.TRACE_SLICE_S) == (1.0, 4.0)
     assert [f.name for f in dataclasses.fields(harness.Run)] == [
         "cell", "nbytes", "calls", "window_s", "setup_s", "peaks", "trace",
-        "spans", "window_compiles"]
+        "spans", "window_compiles", "extra"]
 
 
 SPANS = [

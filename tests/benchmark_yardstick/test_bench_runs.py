@@ -33,19 +33,22 @@ CELLS = ("bert_large.sync", "resnet50.sync")
 SEED = 3_000_000_017  # above 2**31, as the driver's seeds are
 
 
-def _execute(cell, seed=SEED, seconds=1.0, control=False):
+def _execute(cell, seed=SEED, seconds=1.0, control=False, root=ROOT):
     require_native_lib()
     from benchmark import harness
 
     args = argparse.Namespace(workload=cell, seed=seed, seconds=seconds,
                               trace=0)
-    out = harness.execute(args, time.time(), rehearse=True, control=control)
+    out = harness.execute(args, time.time(), rehearse=True, control=control,
+                          root=str(root))
     assert not isinstance(out, int)
     return out
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_runs_the_cell_correctly(cell):
+    from benchmark import traffic
+
     # long enough that every worker reaches its pushes on a loaded host
     line, run, readings = _execute(cell, seconds=2.0)
     assert line["correct"] is True, line["checks"]
@@ -54,8 +57,9 @@ def test_rehearsal_runs_the_cell_correctly(cell):
     assert list(line)[-1] == "checks"
     assert readings["pulls_compared"] > 0
     assert run.window_compiles == 0  # warm-up left nothing to compile
+    # every kind of the mix's step in the window, and no other
     kinds = set(run.calls[:, 0].astype(int))
-    assert kinds == {0, 1}  # pulls and pushes both in the window
+    assert kinds == {traffic.KINDS[op] for op in run.cell.traffic["step"]}
     # The host-clock readers read the rehearsal (it just never prints them).
     for name in ("tensor_GBps", "call_p50_ms", "call_p99_ms", "setup_s"):
         assert run.cell.readers[name](run) > 0
@@ -151,6 +155,8 @@ def test_calibrate_records_the_control_refused(tmp_path):
                for r in result["rows"])
     assert result["summary"]["all_correct"] is True
     assert result["summary"]["any_control_correct"] is False
+    assert result["summary"]["min_control_pull_gap"] > \
+        result["summary"]["max_pull_gap"]
 
 
 def _stale(orig):
@@ -203,7 +209,8 @@ def test_faults_in_the_push_path_make_correct_false(monkeypatch, fault,
     assert c["value"] > c["limit"]
 
 
-def test_an_altered_pull_answer_makes_correct_false(monkeypatch):
+def _alter_pull_answers(monkeypatch):
+    """Every pull answer altered where the server produces it."""
     from brpc_tpu.runtime.param_server import ParameterServer
 
     orig = ParameterServer._encode_pull
@@ -214,7 +221,68 @@ def test_an_altered_pull_answer_makes_correct_false(monkeypatch):
         return out
 
     monkeypatch.setattr(ParameterServer, "_encode_pull", altered)
+
+
+def test_an_altered_pull_answer_makes_correct_false(monkeypatch):
+    _alter_pull_answers(monkeypatch)
     line, _, _ = _execute("resnet50.sync")
+    assert line["correct"] is False
+    assert line["checks"]["pull_gap"]["value"] > \
+        line["checks"]["pull_gap"]["limit"]
+
+
+def _pull_only_tree(tmp_path):
+    """A copy of the benchmark with one more cell, ``resnet50.pull``: the
+    sync mix with its step cut to pulls."""
+    root = tmp_path / "tree"
+    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    mix = json.loads((root / "benchmark" / "traffic" / "sync.json")
+                     .read_text())
+    mix.update(name="pull", step=["pull"])
+    (root / "benchmark" / "traffic" / "pull.json").write_text(
+        json.dumps(mix))
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    bench["workloads"].append({"name": "resnet50.pull", "config": "resnet50",
+                               "traffic": "pull", "chips": 1,
+                               "why": "pulls only"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return root
+
+
+def test_a_pull_only_step_warms_up_and_runs_pulls_alone(monkeypatch,
+                                                         tmp_path):
+    """Warm-up makes only the calls of the mix's step: a step of pulls
+    pushes nothing, before the window or in it, and compiles nothing in
+    the window; the check finds the seeded state."""
+    from brpc_tpu.runtime.param_server import ParameterServer
+
+    from benchmark import traffic
+
+    applied = []
+    orig = ParameterServer._apply_update
+
+    def counted(self, name, att, tracing):
+        applied.append(name)
+        return orig(self, name, att, tracing)
+
+    monkeypatch.setattr(ParameterServer, "_apply_update", counted)
+    line, run, readings = _execute("resnet50.pull", seconds=2.0,
+                                   root=_pull_only_tree(tmp_path))
+    assert line["correct"] is True, line["checks"]
+    assert applied == []
+    assert set(run.calls[:, 0].astype(int)) == {traffic.PULL}
+    assert run.window_compiles == 0
+    assert readings["pulls_compared"] > 0
+    assert readings["pull_gap"] == readings["state_gap"] == 0
+
+
+def test_an_altered_pull_answer_fails_a_pull_only_step(monkeypatch,
+                                                       tmp_path):
+    """Nothing is pushed in a pull-only window, so a wrong pull answer is
+    what its check has to catch."""
+    _alter_pull_answers(monkeypatch)
+    line, _, _ = _execute("resnet50.pull", root=_pull_only_tree(tmp_path))
     assert line["correct"] is False
     assert line["checks"]["pull_gap"]["value"] > \
         line["checks"]["pull_gap"]["limit"]

@@ -77,24 +77,41 @@ def test_config_size_classes_match_the_issue():
 
 # ---- BENCHMARK.json and the files it names ----
 
-def test_benchmark_json_names_files_that_exist():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def check_rules(bench, root):
+    """The rules every BENCHMARK.json of this harness keeps: each piece a
+    file found by name, 1 or 4 chips a cell (at most half the cells on 4,
+    but one always may), every per-layer metric listing the cells it reads
+    in, every mix naming a driver file that exists, or none."""
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    for p in BENCH["paths"]:
-        assert os.path.isdir(os.path.join(ROOT, p))
-    for c in BENCH["configs"]:
-        assert os.path.isfile(os.path.join(ROOT, c["file"]))
-    for w in BENCH["workloads"]:
+    for p in bench["paths"]:
+        assert os.path.isdir(os.path.join(root, p))
+    for c in bench["configs"]:
+        assert os.path.isfile(os.path.join(root, c["file"]))
+    cells = [w["name"] for w in bench["workloads"]]
+    assert len(set(cells)) == len(cells)
+    for w in bench["workloads"]:
+        mix = spec.load_json(os.path.join(
+            root, "benchmark", "traffic", w["traffic"] + ".json"))
         assert os.path.isfile(os.path.join(
-            ROOT, "benchmark", "traffic", w["traffic"] + ".json"))
-        assert w["chips"] == 1 and len(w["why"]) <= 200
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        assert os.path.isfile(os.path.join(ROOT, "benchmark", "metrics",
+            root, "benchmark", "drivers",
+            mix.get("driver", spec.DEFAULT_DRIVER) + ".py"))
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(cells) // 2)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert os.path.isfile(os.path.join(root, "benchmark", "metrics",
                                            m["name"] + ".py"))
-    for m in BENCH["end_to_end"]:
+    for m in bench["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25
-    names = {m["name"] for m in BENCH["end_to_end"]}
-    assert {m["moves"] for m in BENCH["per_layer"]} <= names
+    for m in bench["per_layer"]:
+        assert m["workloads"] and set(m["workloads"]) <= set(cells)
+    names = {m["name"] for m in bench["end_to_end"]}
+    assert {m["moves"] for m in bench["per_layer"]} <= names
+
+
+def test_benchmark_json_names_files_that_exist():
+    check_rules(BENCH, ROOT)
 
 
 def test_full_check_fits_its_time_limit():
@@ -102,13 +119,25 @@ def test_full_check_fits_its_time_limit():
     assert runs * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
 
 
+def own_metrics(bench, cell):
+    """The end-to-end metrics that list no cells or list ``cell``, and the
+    per-layer metrics whose lists name it."""
+    return ({m["name"] for m in bench["end_to_end"]
+             if cell in m.get("workloads", [cell])},
+            {m["name"] for m in bench["per_layer"] if cell in m["workloads"]})
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_every_cell_loads_with_all_its_metrics(cell):
     c = spec.load_cell(cell)
-    assert {m["name"] for m in c.end_to_end} == {
-        "tensor_GBps", "call_p50_ms", "call_p99_ms", "setup_s"}
-    assert len(c.per_layer) == 5
+    e2e, per = own_metrics(BENCH, cell)
+    assert e2e == {"tensor_GBps", "call_p50_ms", "call_p99_ms", "setup_s"}
+    assert {m["name"] for m in c.end_to_end} == e2e
+    assert {m["name"] for m in c.per_layer} == per
+    if c.traffic["name"] == "sync":
+        assert len(per) == 5
     assert set(c.readers) == {m["name"] for m in c.end_to_end + c.per_layer}
+    assert c.driver.__name__.endswith(spec.DEFAULT_DRIVER)
 
 
 def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
@@ -134,6 +163,139 @@ def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
     assert cell.config["name"] == "bert_large"
     assert {p: (root / "benchmark" / p).read_bytes()
             for p in before} == before
+
+
+# A cell that brings its own driver, mix keys, check, chip count and
+# per-layer metric, each as a new file: seeded rows summed on every chip of
+# the cell, compared with numpy's sum.
+TOY_DRIVER = '''\
+"""Seeded rows, summed again and again on each chip of the cell."""
+import time
+
+import numpy as np
+
+from benchmark import harness
+
+KEYS = {"rows"}
+
+
+def check_mix(mix):
+    if int(mix.get("rows", 0)) < 1:
+        raise ValueError("toy: needs rows >= 1")
+
+
+def run(cell, args, session, rehearse=False, control=False):
+    import jax
+    import jax.numpy as jnp
+
+    rows = int(cell.traffic["rows"])
+    host = np.random.default_rng(args.seed).standard_normal(
+        (rows, 128)).astype(np.float32)
+    xs = [jax.device_put(host, d) for d in session.devices]
+    session.mark("data")
+    total = jax.jit(jnp.sum)
+    for x in xs:
+        total(x).block_until_ready()
+    session.mark("warm_up")
+    calls, sums = [], {}
+
+    def body(seconds, during):
+        t_open = time.perf_counter()
+        while time.perf_counter() < t_open + seconds:
+            for i, x in enumerate(xs):
+                t0 = time.perf_counter()
+                sums[i] = float(total(x))
+                calls.append((0, i, 0, t0, time.perf_counter()))
+        return t_open, time.perf_counter()
+
+    session.window(body, describe=f"{len(xs)} chips, {rows} rows",
+                   detail=lambda: f"{len(calls)} sums")
+    want = float(host.astype(np.float64).sum())
+    gap = max(abs(s - want) for s in sums.values())
+    limit = float(cell.config["limits"]["sum_gap"])
+    return harness.Outcome(
+        nbytes=[host.nbytes] * len(xs),
+        calls=np.array(calls, np.float64).reshape(-1, 5),
+        window_s=session.window_s, failed=0, errors=[],
+        checks={"sum_gap": {"value": gap, "limit": limit}},
+        readings={"sum_gap": gap}, extra={"sums": len(calls)})
+'''
+
+TOY_READER = '''\
+"""Sums completed per second of the window (the driver's own count)."""
+
+
+def read(run):
+    n = run.extra.get("sums")
+    return n / run.window_s if n and run.window_s > 0 else None
+'''
+
+
+def test_a_cell_with_its_own_driver_is_added_by_files_alone(tmp_path):
+    """A driver, its mix, a configuration, a 4-chip cell and a per-layer
+    metric of its own: new files and entries only, loaded with exactly its
+    metrics and rehearsed to a correct line on the CPU."""
+    import argparse
+    import time
+
+    from benchmark import harness
+
+    root = tmp_path / "tree"
+    for p in BENCH["paths"]:
+        shutil.copytree(os.path.join(ROOT, p), root / p,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    files = {
+        "drivers/toy_sum.py": TOY_DRIVER,
+        "traffic/toy.json": json.dumps({"name": "toy", "driver": "toy_sum",
+                                        "rows": 64, "why": "a toy"}),
+        "configs/toy.json": json.dumps({"name": "toy",
+                                        "limits": {"sum_gap": 0.01}}),
+        "metrics/toy_sums_per_s.py": TOY_READER,
+    }
+    for rel, text in files.items():
+        assert not (root / "benchmark" / rel).exists()
+        (root / "benchmark" / rel).write_text(text)
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({"name": "toy", "source": "a test",
+                             "file": "benchmark/configs/toy.json",
+                             "reduced": [], "why": "a toy"})
+    bench["workloads"].append({"name": "toy.sum", "config": "toy",
+                               "traffic": "toy", "chips": 4,
+                               "why": "sums on four chips"})
+    bench["per_layer"].append({"name": "toy_sums_per_s", "unit": "1/s",
+                               "better": "higher", "source": "program_counter",
+                               "layer": "toy", "moves": "tensor_GBps",
+                               "workloads": ["toy.sum"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    assert {p: p.read_bytes() for p in before} == before
+    check_rules(bench, str(root))
+
+    cell = spec.load_cell("toy.sum", root=str(root))
+    e2e, per = own_metrics(bench, "toy.sum")
+    assert per == {"toy_sums_per_s"} and cell.chips == 4
+    assert {m["name"] for m in cell.end_to_end} == e2e
+    assert {m["name"] for m in cell.per_layer} == per
+
+    args = argparse.Namespace(workload="toy.sum", seed=3_000_000_019,
+                              seconds=0.5, trace=0)
+    line, run, readings = harness.execute(args, time.time(), rehearse=True,
+                                          root=str(root))
+    assert line["correct"] is True
+    assert list(line["checks"]) == ["sum_gap"] and list(line)[-1] == "checks"
+    assert line["checks"]["sum_gap"]["value"] <= 0.01
+    assert line["checks"]["sum_gap"]["limit"] == 0.01
+    assert line["device"]["count"] == 4 and run.extra["sums"] > 0
+    assert len(run.nbytes) == 4  # the harness handed the driver four chips
+    assert cell.readers["toy_sums_per_s"](run) == pytest.approx(
+        run.extra["sums"] / run.window_s)
+
+    for mix in ({"name": "x", "driver": "nonesuch"},
+                {"name": "x", "driver": "../drivers/toy_sum"},
+                {"name": "x", "driver": "toy_sum", "rows": 4, "rate_qps": 5},
+                {"name": "x", "driver": "toy_sum", "rows": 0}):
+        with pytest.raises(ValueError):
+            spec.check_traffic(mix, root=str(root))
 
 
 def test_a_mix_the_generator_cannot_run_is_refused():
