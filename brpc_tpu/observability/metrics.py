@@ -203,6 +203,17 @@ def gauge(name: str, fn: Callable[[], int]) -> PassiveGauge:
                           lambda: PassiveGauge(name, fn))
 
 
+def trainer_series() -> Dict[str, object]:
+    """The layered trainer's series (models/mla_moe.py), per completed
+    step: ``trainer_tokens`` counts its tokens; ``trainer_expert_slots``
+    takes one sample per held expert of each expert layer, the token-slots
+    routed to it; ``trainer_expert_load_permille`` one sample per expert
+    layer, the largest of those over their mean, in thousandths."""
+    return {"tokens": counter("trainer_tokens"),
+            "expert_slots": latency("trainer_expert_slots"),
+            "expert_load": latency("trainer_expert_load_permille")}
+
+
 # Roles that restart within one process (fleet clients, migrators, a
 # re-created named server) can't re-register their gauges — registrations
 # are immortal and keep the original callback. These route reads through a

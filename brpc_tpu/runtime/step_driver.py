@@ -35,6 +35,13 @@ a child span per node (wire-side spans carry the PipelineWindow's
 annotations, so a trace VISIBLY shows push spans inside the next layer's
 compute span), plus ``step_exposed_comm_ms`` / ``step_overlapped_comm_ms``
 recorders on /vars (samples in milliseconds, as named).
+
+Memory: the driver holds one copy of the parameter set, not three. A
+parameter is released once its layer's backward has run (the next
+version is already on its way through the pull lane), and a gradient once
+its push is staged into the channel's arena. Each push's acknowledgement
+(the new version, and when it arrived) lands in the step's
+``RunTrace.acks``.
 """
 
 from __future__ import annotations
@@ -116,10 +123,16 @@ class OverlappedStepDriver:
       * ``forward(params, x, y) -> ctx``;
       * ``backward(ctx, name) -> grad`` (called top layer first);
       * ``loss(ctx) -> float``.
+
+    ``call_span(kind, name)`` (optional) returns a context manager entered
+    around each pull (``kind`` ``"pull"``) and each push submit
+    (``"push"``) on the wire lane: a caller's own span or timer per call,
+    which the tensor path's stage annotations (``rpc``, ``device_put``,
+    ``arena_stage``) land on.
     """
 
     def __init__(self, client, harness, overlap: bool = True,
-                 window: int = 4):
+                 window: int = 4, call_span=None):
         self.client = client
         self.harness = harness
         self.overlap = overlap
@@ -130,6 +143,7 @@ class OverlappedStepDriver:
         self._m = _metrics()
         self.last_stats: Optional[dict] = None
         self.last_trace = None  # RunTrace of the last SUCCESSFUL step
+        self.call_span = call_span
         self.totals = {"steps": 0, "wall_ms": 0.0, "compute_ms": 0.0,
                        "wire_busy_ms": 0.0, "exposed_comm_ms": 0.0,
                        "overlapped_comm_ms": 0.0}
@@ -182,10 +196,14 @@ class OverlappedStepDriver:
         push_failed: Dict[str, BaseException] = {}
         ctx_box: Dict[str, object] = {}
         channel = getattr(self.client, "channel", None)
+        acks: Dict[str, tuple] = {}
+        call_span = self.call_span or (lambda kind, name:
+                                       contextlib.nullcontext())
 
         def on_push_reply(tag, payload, view):
             view.release()  # push responses carry no tensor
             step_versions[tag] = int(payload.decode())
+            acks[tag] = (step_versions[tag], time.monotonic())
 
         win = (PipelineWindow(channel, self.window, on_reply=on_push_reply)
                if channel is not None else None)
@@ -206,6 +224,13 @@ class OverlappedStepDriver:
             for name, arr in self._raw.items():
                 self._params[name] = self.harness.place(name, arr)
             self._raw.clear()
+            # A failed step released the parameters whose pulls it
+            # cancelled: fetch the server's current version of those.
+            for name in names:
+                if name not in self._params:
+                    version, arr = self.client.pull(name)
+                    self._params[name] = self.harness.place(name, arr)
+                    self.versions[name] = version
             ctx_box["ctx"] = self.harness.forward(self._params, x, y)
             return None
 
@@ -216,6 +241,7 @@ class OverlappedStepDriver:
                 # compute lane (and the wire lane's staging D2H reads a
                 # finished array instead of blocking on dispatch).
                 grads[name] = jax.block_until_ready(g)
+                self._params.pop(name, None)
                 return None
             return fn
 
@@ -251,15 +277,19 @@ class OverlappedStepDriver:
                     enc = self.client._grad_encoder(name)
                     if enc is not None:
                         enc = _staged_encode(enc)
-                    win.submit("ParamService/Push", array=grads[name],
-                               request=name.encode(), tag=name,
-                               encoder=enc)
-                    push_bytes.add(int(getattr(grads[name], "nbytes", 0)))
+                    grad = grads.pop(name)
+                    with call_span("push", name):
+                        win.submit("ParamService/Push", array=grad,
+                                   request=name.encode(), tag=name,
+                                   encoder=enc)
+                    push_bytes.add(int(getattr(grad, "nbytes", 0)))
                     return None
             else:
                 def fn(done):
-                    step_versions[name] = self.client.push_grad(
-                        name, grads[name])
+                    with call_span("push", name):
+                        step_versions[name] = self.client.push_grad(
+                            name, grads.pop(name))
+                    acks[name] = (step_versions[name], time.monotonic())
                     return None
             return fn
 
@@ -288,7 +318,8 @@ class OverlappedStepDriver:
 
         def make_pull(name):
             def fn(done):
-                version, arr = self.client.pull(name)
+                with call_span("pull", name):
+                    version, arr = self.client.pull(name)
                 self._raw[name] = arr
                 self.versions[name] = version
                 return version
@@ -354,6 +385,7 @@ class OverlappedStepDriver:
                     self.versions[name] = max(
                         self.versions.get(name, 0), v)
             if trace is not None:
+                trace.acks = acks
                 wall_ms = trace.wall_s * 1e3
                 exposed_ms = trace.exposed_wait_s * 1e3
                 overlapped_ms = trace.overlapped_comm_s() * 1e3

@@ -163,7 +163,9 @@ class RunTrace:
     track-and-trigger fusion (ISSUE 20) attacks exactly the join tail,
     and only this split makes its delta attributable. ``lane_join_s``
     attributes the join tail per wire lane: how far past the barrier each
-    lane's last node ended.
+    lane's last node ended. ``acks``: ``{name: (version, t)}`` of each push
+    acknowledgement a parameter-server step handled (monotonic clock),
+    filled by the step driver.
     """
 
     def __init__(self, overlap: bool):
@@ -177,6 +179,7 @@ class RunTrace:
         self.lane_join_s: Dict[str, float] = {}
         self.compute_busy_s = 0.0
         self.wall_s = 0.0
+        self.acks: Dict[str, Tuple[int, float]] = {}
 
     def span(self, name: str) -> Optional[Tuple[float, float]]:
         for n, _lane, s, e in self.events:

@@ -84,6 +84,11 @@ def test_stall_detection_autodump_and_recovery(health, tmp_path):
                         "health to reach stalled")
             doc = health.health()
             assert "scheduler" in doc["reason"]
+            # The watchdog flips the state first and then writes the dump
+            # (fiber stacks symbolized): on a loaded host the file lands
+            # a moment after "stalled" reads.
+            _wait_until(health.last_dump_path, 10,
+                        "the auto-dump of entering stalled")
             path = health.last_dump_path()
             assert path and os.path.exists(path), \
                 "entering stalled must auto-dump"

@@ -33,6 +33,7 @@ dump:
     same scheduled step over a live ParameterServer.
 """
 
+import contextlib
 import threading
 import time
 
@@ -628,3 +629,95 @@ def test_mesh_harness_drives_scheduled_step(overlap_env):
     for n in finals[True]:
         np.testing.assert_allclose(finals[True][n], finals[False][n],
                                    rtol=1e-6, atol=1e-8)
+
+
+def test_driver_releases_parameters_and_staged_gradients(overlap_env):
+    """The driver keeps one copy of the parameter set: after ``bwd:k``
+    neither the driver nor the harness's ctx holds layer k's parameters,
+    and a gradient is dropped once its push is staged into the arena. Each
+    pull and push submit runs inside the caller's ``call_span``, and each
+    push's acknowledgement (version, arrival) lands in the step's
+    ``RunTrace.acks``. The trainer's counters reach /vars."""
+    import gc
+    import weakref
+
+    import jax
+
+    from brpc_tpu.models import mla_moe
+    from brpc_tpu.observability import metrics as obs
+    from brpc_tpu.runtime.param_server import (ParameterClient,
+                                               ParameterServer)
+    from brpc_tpu.runtime.step_driver import OverlappedStepDriver
+
+    hf = dict(hidden_size=32, num_hidden_layers=2, first_k_dense_replace=1,
+              num_attention_heads=2, kv_lora_rank=8, qk_nope_head_dim=8,
+              qk_rope_head_dim=4, v_head_dim=8, rope_theta=50000,
+              rms_norm_eps=1e-5, intermediate_size=48,
+              moe_intermediate_size=16, n_shared_experts=1, router_experts=8,
+              held_experts=[0, 1], n_routed_experts=2, num_experts_per_tok=3,
+              routed_scaling_factor=2.0, vocab_size=64)
+    cfg = mla_moe.Config.from_hf(hf, q_block=8, loss_chunk=16)
+    trainer = mla_moe.MlaMoeTrainer(cfg, np.zeros((1, 8), np.float32))
+
+    class Spy:
+        names = trainer.names
+
+        def __init__(self):
+            self.done, self.grads, self.violations = [], [], []
+
+        def place(self, name, arr):
+            return arr
+
+        def forward(self, params, x, y):
+            return trainer.forward(params, x, y)
+
+        def backward(self, ctx, name):
+            held = set(self.done) & (set(driver._params)
+                                     | set(ctx["params"]))
+            if held:
+                self.violations.append((name, sorted(held)))
+            g = trainer.backward(ctx, name)
+            self.grads.append(weakref.ref(g))
+            self.done.append(name)
+            return g
+
+        def loss(self, ctx):
+            return trainer.loss(ctx)
+
+    spans = []
+
+    @contextlib.contextmanager
+    def call_span(kind, name):
+        t0 = time.monotonic()
+        yield
+        spans.append((kind, name, t0))
+
+    spy = Spy()
+    ps = ParameterServer(dict(mla_moe.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    client = ParameterClient(f"tpu://127.0.0.1:{ps.start()}")
+    try:
+        driver = OverlappedStepDriver(client, spy, overlap=True, window=2,
+                                      call_span=call_span)
+        driver.prime()
+        x, y = mla_moe.step_tokens(cfg, 1, 16, jax.random.PRNGKey(1))
+        driver.step(x, y)
+        assert spy.violations == []
+        assert spy.done == list(reversed(trainer.names))
+        assert driver._params == {} and set(driver._raw) == set(spy.names)
+        gc.collect()
+        assert all(r() is None for r in spy.grads)
+        for kind in ("pull", "push"):
+            assert sorted(n for k, n, _ in spans if k == kind) == \
+                sorted(spy.names)
+        acks = driver.last_trace.acks
+        assert set(acks) == set(spy.names)
+        submitted = {n: t for k, n, t in spans if k == "push"}
+        assert all(v == 1 and submitted[n] <= t for n, (v, t) in acks.items())
+        # the next step places what the last one pulled
+        driver.step(x, y)
+        assert all(v == 2 for v in driver.versions.values())
+        assert "trainer_tokens" in obs.dump_vars("trainer_")
+    finally:
+        client.close()
+        ps.stop()
