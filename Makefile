@@ -1,6 +1,6 @@
 BUILD_DIR := native/build
 
-.PHONY: native test soak asan tsan test-asan test-tsan tsan-test asan-test contract-check lint lint-sarif bench-smoke obs-smoke serve-smoke serving-fleet-smoke spec-smoke paged-smoke train-smoke collectives-smoke clean
+.PHONY: native test soak asan tsan test-asan test-tsan tsan-test asan-test contract-check lint lint-sarif obs-smoke serve-smoke serving-fleet-smoke spec-smoke paged-smoke train-smoke collectives-smoke clean
 
 native:
 	cmake -S native -B $(BUILD_DIR) -G Ninja -DCMAKE_BUILD_TYPE=RelWithDebInfo >/dev/null
@@ -28,17 +28,10 @@ contract-check:
 	python -m tools.tpulint --no-baseline brpc_tpu examples
 	python -m tools.tpulint
 
-# ~10s perf sanity sweep: one subprocess-guarded 64B echo sample + a
-# 4x1MB pipelined pull point. Every sample runs under a hard timeout, so
-# a transport wedge records {"wedged": true} instead of hanging the
-# terminal (or tier-1).
-bench-smoke:
-	python bench.py --smoke
-
-# Fast local gate for the fleet observability plane (the bench-smoke
-# analog): the cross-process trace-assembly + /fleetz scrape + sampling
-# tests, then lint. The pure assembly/skew tests run even without the
-# native library; the live-fleet halves skip cleanly there.
+# Fast local gate for the fleet observability plane: the cross-process
+# trace-assembly + /fleetz scrape + sampling tests, then lint. The pure
+# assembly/skew tests run even without the native library; the
+# live-fleet halves skip cleanly there.
 obs-smoke:
 	python -m pytest tests/test_fleet_view.py -q
 	$(MAKE) --no-print-directory contract-check

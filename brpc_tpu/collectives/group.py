@@ -171,13 +171,6 @@ class _RpcLink:
                    "step": int(step), "frag": int(frag),
                    "ep": self.g._epoch, "src": self.g.rank})
         self.wire_bytes += int(concat.nbytes)
-        if self.g.emulate_wire_gbps:
-            # Bench-only link emulation: serialize this fragment's BYTES
-            # through a modeled uplink (loopback shm runs at memcpy
-            # speed, which no real cross-host fleet link does — this is
-            # how the wire-BOUND regime is measured on a one-box CI).
-            time.sleep(  # tpulint: allow(py-blocking)
-                concat.nbytes / (self.g.emulate_wire_gbps * 1e9))
         win = self._window(addr)
         tag = (phase, int(step), int(frag))
         self._inflight[addr][tag] = (manifest, concat)
@@ -279,8 +272,8 @@ class CollectiveGroup:
 
     ``codec="int8"`` (or ``"fp8e4m3"``) quantizes every hop against
     peers that advertise it; ``ef=False`` is the naive requantizer (the
-    pinned negative control — linearly compounding error, bench/test
-    only). ``tree_max_bytes`` routes tensors at or below it through the
+    pinned negative control — linearly compounding error, test only).
+    ``tree_max_bytes`` routes tensors at or below it through the
     2-hop tree instead of the 2(n-1)-hop ring (the latency/bandwidth
     crossover for small tensors)."""
 
@@ -293,7 +286,6 @@ class CollectiveGroup:
                  arena_bytes: int = 64 << 20,
                  client_arena_bytes: int = 32 << 20,
                  ttl_s: int = 5, tenant: str = "",
-                 emulate_wire_gbps: Optional[float] = None,
                  name: Optional[str] = None):
         self._registry = registry_hostport
         self.tag = tag
@@ -303,10 +295,6 @@ class CollectiveGroup:
         # ~1MB wire fragments measured fastest on this transport (bigger
         # single attachments LOSE throughput — 8MB monoliths ran ~0.5x).
         self.frag_elems = max(1, frag_bytes // 4)
-        # Bench-only: emulate a bounded cross-host link (GB/s) by
-        # serializing each fragment's wire bytes at the sender. None =
-        # the real transport. Never set in production paths.
-        self.emulate_wire_gbps = emulate_wire_gbps
         self._client_arena_bytes = client_arena_bytes
         self._tenant = tenant
         self._codec_name = codec

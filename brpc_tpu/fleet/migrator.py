@@ -308,12 +308,11 @@ class Migrator:
         Handoff/Install/Retire/Commit the ParameterServer enforces. A
         Handoff ships the stacked ``[param, momentum]`` pair at its
         version, so optimizer state rides the switch for free and the
-        post-switch trajectory stays on the pre-switch one (parity is
-        pinned in the bench's regime_switch row). Training steps lost =
-        however many steps the caller pauses around this call — the
-        freeze is per tensor inside the stream, so pushes racing the
-        switch fail fast with "frozen"/"moved:<dst>" rather than
-        landing on a stale owner."""
+        post-switch trajectory stays on the pre-switch one. Training
+        steps lost = however many steps the caller pauses around this
+        call — the freeze is per tensor inside the stream, so pushes
+        racing the switch fail fast with "frozen"/"moved:<dst>" rather
+        than landing on a stale owner."""
         self._overrides = dict(assignment)
         return self.reshard(index, addrs)
 

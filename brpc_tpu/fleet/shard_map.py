@@ -6,8 +6,8 @@ proper): md5("addr-rep") digests yield four 32-bit ring points each, 100
 vnodes per weight unit, and a key routes to the first point clockwise of
 the low-32 bits of md5(key). Reimplementing the layout here (instead of
 binding the C++ ring) keeps the map computable by ANY fleet participant
-from the registry's membership list alone — client, migrator and bench
-all derive byte-identical ownership with no coordination RPC.
+from the registry's membership list alone — client and migrator both
+derive byte-identical ownership with no coordination RPC.
 
 Ketama's zero-collateral property (pinned natively by test_lb.cpp
 ketama_remap_fraction_on_removal, and at the fleet level by

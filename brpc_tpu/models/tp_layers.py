@@ -19,8 +19,7 @@ lands on a wire lane — the regime-graph lint class). The wrappers are
 duck-typed over ``group``: anything with ``rank``/``world``/
 ``reduce_scatter``/``allgather`` works — a real wire group, or the
 in-process :class:`LocalRing` below (same ``collectives.core``
-algorithms over a Mailbox transport) for tests and single-process bench
-baselines.
+algorithms over a Mailbox transport) for tests.
 
 Sharding layout is ``ring.chunk_spans(dim, world)`` by RANK INDEX — a
 static partition, deliberately the same balanced-spans helper the ring
@@ -222,7 +221,7 @@ class TPShardedMLP:
 
 
 # ---------------------------------------------------------------------------
-# LocalRing: the pure in-process group (tests, single-process bench).
+# LocalRing: the pure in-process group (tests).
 # ---------------------------------------------------------------------------
 
 class _MemLink:

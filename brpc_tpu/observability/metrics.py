@@ -101,12 +101,6 @@ class LatencyRecorder:
     def p999(self) -> int:
         return self._v(999)
 
-    def snapshot(self) -> Dict[str, int]:
-        """The BENCH-json row: framework-recorded percentiles (us)."""
-        return {"count": self.count(), "avg_us": self.avg_us(),
-                "p50_us": self.p50(), "p99_us": self.p99(),
-                "max_us": self.max_us()}
-
 
 class PassiveGauge:
     """A native PassiveStatus<int64> whose value is `fn()` at scrape time.

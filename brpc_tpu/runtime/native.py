@@ -2,10 +2,10 @@
 
 The host RPC fabric (fiber scheduler, wait-free sockets, tstd protocol) is
 C++; this module is the Python doorway: Server/Channel objects, Python
-service handlers, and the bench harness entry points whose hot loops stay
-in C. Handlers run on a small DEDICATED PTHREAD POOL on the native side
-(capi PyCallbackPool, python_callback_threads flag), never on a fiber:
-ctypes pairs PyGILState_Ensure/Release on one OS thread, and a fiber that
+service handlers, and an echo load loop that stays in C. Handlers run
+on a small DEDICATED PTHREAD POOL on the native side (capi
+PyCallbackPool, python_callback_threads flag), never on a fiber: ctypes
+pairs PyGILState_Ensure/Release on one OS thread, and a fiber that
 parks mid-handler (e.g. a nested RPC) could resume on a different worker.
 The service fiber parks until the handler returns, and the handler's
 thread carries the server's rpcz trace context, so downstream calls made
@@ -198,7 +198,7 @@ def build(target: Optional[str] = None) -> str:
         raise RuntimeError("building native/ needs cmake and ninja on PATH")
     # Runs before any server, channel, or fiber exists — there is no
     # handler path to stall yet. The file lock serializes processes that
-    # build at once (test workers, a bench and its children): the second
+    # build at once (test workers, fleet members): the second
     # one finds the tree configured and its build is a no-op.
     with open(os.path.join(_REPO, "native", ".build.lock"), "w") as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)  # tpulint: allow(py-blocking)
@@ -260,17 +260,9 @@ def lib() -> ctypes.CDLL:
     L.tbrpc_alloc.restype = ctypes.c_void_p
     L.tbrpc_alloc.argtypes = [ctypes.c_size_t]
     L.tbrpc_free.argtypes = [ctypes.c_void_p]
-    L.tbrpc_bench_echo_throughput.restype = ctypes.c_double
-    L.tbrpc_bench_echo_throughput.argtypes = [
-        ctypes.c_size_t, ctypes.c_int, ctypes.c_int]
     L.tbrpc_bench_echo_qps.restype = ctypes.c_double
     L.tbrpc_bench_echo_qps.argtypes = [
         ctypes.c_int, ctypes.c_int, ctypes.POINTER(ctypes.c_double)]
-    L.tbrpc_bench_echo_ex.restype = ctypes.c_double
-    L.tbrpc_bench_echo_ex.argtypes = [
-        ctypes.c_size_t, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-        ctypes.c_int, ctypes.POINTER(ctypes.c_double),
-        ctypes.POINTER(ctypes.c_double), ctypes.POINTER(ctypes.c_double)]
     # ---- observability: metrics + dumps + tracing (capi.h) ----
     L.tbrpc_var_adder_create.restype = ctypes.c_void_p
     L.tbrpc_var_adder_create.argtypes = [ctypes.c_char_p]
@@ -596,7 +588,7 @@ class Server:
     def set_max_concurrency(self, max_inflight: int) -> None:
         """Concurrency gate applied at start() (0 = unlimited). Requests
         over the cap shed with ELIMIT + a retry_after_ms hint; the BULK
-        lane additionally keeps rpc_bulk_headroom_pct of the gate free
+        lane additionally keeps 10% of the gate free
         for control-plane traffic. Must be called BEFORE start()."""
         if self._L.tbrpc_server_set_max_concurrency(
                 self._h, max_inflight) != 0:
@@ -978,34 +970,8 @@ def progressive_close(progressive_id: int) -> None:
     lib().tbrpc_progressive_close(progressive_id)
 
 
-def bench_echo_throughput(payload_size: int, seconds: int = 2,
-                          concurrency: int = 4) -> float:
-    """One-way payload bytes/sec through a loopback echo server."""
-    return lib().tbrpc_bench_echo_throughput(payload_size, seconds,
-                                             concurrency)
-
-
 def bench_echo_qps(seconds: int = 2, concurrency: int = 8):
     """(calls/sec, p99_us) for small-payload loopback echo."""
     p99 = ctypes.c_double()
     qps = lib().tbrpc_bench_echo_qps(seconds, concurrency, ctypes.byref(p99))
     return qps, p99.value
-
-
-def bench_echo_ex(payload_size: int, seconds: int = 2, concurrency: int = 4,
-                  transport: str = "tcp", conn_type: str = "single"):
-    """One bench point with full control.
-
-    Returns (oneway_bytes_per_sec, calls_per_sec, p50_us, p99_us).
-    transport: "tcp" | "tpu" (shm ICI transport over the loopback control
-    channel). conn_type: "single" | "pooled" | "short".
-    """
-    qps = ctypes.c_double()
-    p50 = ctypes.c_double()
-    p99 = ctypes.c_double()
-    bps = lib().tbrpc_bench_echo_ex(
-        payload_size, seconds, concurrency,
-        {"tcp": 0, "tpu": 1}[transport],
-        {"single": 0, "pooled": 1, "short": 2}[conn_type],
-        ctypes.byref(qps), ctypes.byref(p50), ctypes.byref(p99))
-    return bps, qps.value, p50.value, p99.value

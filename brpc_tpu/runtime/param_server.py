@@ -253,8 +253,8 @@ class ParameterServer:
         # Backend split for the Push hot path. On TPU the update is the
         # fused Pallas kernel over device arrays (device_put = a real H2D
         # DMA). On the CPU backend that same shape is all dispatch
-        # overhead: per-push jax dispatch (~0.5ms) dominated the pipelined
-        # bench, and device_put ZERO-COPY ALIASES 64B-aligned host buffers
+        # overhead: per-push jax dispatch (~0.5ms) dominated a pipelined
+        # drive, and device_put ZERO-COPY ALIASES 64B-aligned host buffers
         # — with the update dispatched async, the grad view's arena range
         # could be reused under the pending computation. The CPU path
         # keeps params/momenta as numpy and applies the update
@@ -1337,11 +1337,11 @@ class ParameterClient:
 
     # ---- pipelined multi-tensor hot path (PipelineWindow) ----
     # The serial pull/push above pay one full round-trip per tensor: a
-    # model with N parameter tensors pays N x the ~260us 1MB latency
-    # floor (old run, records deleted in PR 21) although the transport sustains ~3x the
-    # single-stream throughput at conc=8 (BENCH r05). These keep a
-    # bounded window of RPCs in flight instead, so N tensors cost ~1
-    # round-trip plus N wire times.
+    # model with N parameter tensors pays N round-trip latency floors
+    # although the transport sustains more than one stream's throughput
+    # with several calls in flight. These keep a bounded window of RPCs
+    # in flight instead, so N tensors cost ~1 round-trip plus N wire
+    # times.
 
     def pull_all(self, names=None, device=None, window: int = 4,
                  group: int = 8, to_host: bool = False,

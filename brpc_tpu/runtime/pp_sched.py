@@ -347,8 +347,7 @@ class WirePipe:
                  tag: str = "pp", listen: str = "127.0.0.1:0",
                  window: int = 4, timeout_s: float = 30.0,
                  arena_bytes: int = 64 << 20,
-                 client_arena_bytes: int = 32 << 20, ttl_s: int = 5,
-                 emulate_wire_gbps: Optional[float] = None):
+                 client_arena_bytes: int = 32 << 20, ttl_s: int = 5):
         from brpc_tpu.fleet import registry
         from brpc_tpu.runtime import native
         from brpc_tpu.runtime.tensor import TensorArena, \
@@ -359,7 +358,6 @@ class WirePipe:
         self.tag = tag
         self.timeout_s = timeout_s
         self.window = window
-        self.emulate_wire_gbps = emulate_wire_gbps
         self._client_arena_bytes = client_arena_bytes
         self._registry = registry_hostport
         self._box = _Box()
@@ -455,15 +453,6 @@ class WirePipe:
               arr) -> None:
         req = json.dumps({"kind": kind, "step": step, "mb": mb}).encode()
         host = np.ascontiguousarray(np.asarray(arr), dtype=np.float32)
-        if self.emulate_wire_gbps:
-            # Bench-only link emulation, the CollectiveGroup discipline:
-            # serialize this tensor's bytes through a modeled uplink —
-            # loopback shm runs at memcpy speed, which no cross-host
-            # stage link does, so this is how the wire-BOUND regime is
-            # measured on a one-box CI. Runs on the send node's wire
-            # lane, never in a handler.
-            time.sleep(  # tpulint: allow(py-blocking)
-                host.nbytes / (self.emulate_wire_gbps * 1e9))
         with self._mu:
             self._wins[direction].submit("PipeStage/Ship", array=host,
                                          request=req,

@@ -42,7 +42,7 @@ planes (paging, export and one-sided publication see committed rows
 only), and the draft plane rewinds the same way. Per-session ``spec_k``
 adapts on an acceptance-rate EMA (floor 1; all-prompt windows don't
 count); ``engine.spec_k = 0`` is the live kill switch — the verbatim
-single-token path — and the bench's A/B toggle (Gen/Spec).
+single-token path — also settable live over the wire (Gen/Spec).
 """
 
 from __future__ import annotations
@@ -85,7 +85,7 @@ class DecodeEngine:
         self.eos_id = eos_id
         self.step_idle_s = step_idle_s
         # Speculative decoding config. spec_k is a PLAIN attribute read
-        # once per step: setting it live (Gen/Spec, tests, bench A/B)
+        # once per step: setting it live (Gen/Spec, tests)
         # takes effect at the next step boundary, and 0 is the kill
         # switch — the verbatim single-token path.
         if draft not in ("ngram", "model"):

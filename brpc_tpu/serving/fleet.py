@@ -176,7 +176,7 @@ class FleetServingServer(ServingServer):
             return json.dumps({"moved": self.forwarded_to(
                 str(doc.get("session", "")))}).encode(), b""
         if method == "Drain":
-            # Admin trigger (bench/tests drive cross-process drains with
+            # Admin trigger (tests drive cross-process drains with
             # it): runs async — the response must not wait out the ship.
             threading.Thread(target=self.drain, daemon=True,
                              name="serving-drain").start()

@@ -213,8 +213,8 @@ class FleetTokenStream:
     """A TokenStream that survives migrations: reads follow
     E_SESSION_MOVED closes / "moved:" E-frames through ``Gen/Resume``
     transparently. ``tokens`` is the full prefix-exact list;
-    ``resumes``/``last_gap_s`` expose the migration cost (the bench's
-    stream-gap statistic)."""
+    ``resumes``/``last_gap_s`` expose the migration cost (the stream
+    gap)."""
 
     def __init__(self, client: "ServingFleetClient", session_id: str,
                  ts: TokenStream, addr: str):

@@ -87,7 +87,7 @@ class ServingServer:
             ok = self.manager.close(str(doc.get("session", "")))
             return json.dumps({"closed": bool(ok)}).encode(), b""
         if method == "Spec":
-            # Live speculative-decoding toggle (admin/bench A/B): set
+            # Live speculative-decoding toggle (admin): set
             # spec_k for the NEXT step boundary onwards; 0 is the kill
             # switch (the verbatim single-token path). Answers the
             # previous value so a driver can restore it.

@@ -3,7 +3,7 @@
 ``force_virtual_cpu_devices`` pins the CPU backend with N virtual devices
 (the tests' multi-device mesh); ``enable_compile_cache`` turns on JAX's
 persistent compilation cache for the chip entry points (``chip_smoke.py``,
-``bench.py``). Both must run before the first JAX backend touch: the
+``benchmark/run.py``). Both must run before the first JAX backend touch: the
 platform choice and the cache directory are process-global.
 """
 

@@ -2024,7 +2024,7 @@ int tbrpc_progressive_close(uint64_t progressive_id) {
   return 0;
 }
 
-// ---------------- bench harness ----------------
+// ---------------- echo load loop (tbrpc_bench_echo_qps) ----------------
 
 namespace {
 
@@ -2033,23 +2033,20 @@ struct BenchEnv {
   ChannelBox* channel = nullptr;
   bool ok = false;
 
-  explicit BenchEnv(bool tpu = false, int conn_type = 0) {
+  BenchEnv() {
     server = new ServerBox;
     tbrpc_server_add_echo_service(server);
     // The native echo handler is non-blocking: register it on the inline
-    // fast path (inert while rpc_dispatch_batch_max == 1, so the
-    // per-message A/B mode still measures the seed regime).
+    // fast path.
     tbrpc_server_set_inline(server, "EchoService", 1);
     int port = tbrpc_server_start(server, "127.0.0.1:0");
     if (port <= 0) return;
     char addr[48];
-    snprintf(addr, sizeof(addr), "%s127.0.0.1:%d", tpu ? "tpu://" : "",
-             port);
+    snprintf(addr, sizeof(addr), "127.0.0.1:%d", port);
     auto* box = new ChannelBox;
     ChannelOptions opts;
     opts.timeout_ms = 20000;
     opts.max_retry = 0;
-    opts.connection_type = static_cast<ConnectionType>(conn_type);
     if (box->channel.Init(addr, &opts) != 0) {
       delete box;
       return;
@@ -2066,52 +2063,15 @@ struct BenchEnv {
 
 }  // namespace
 
-double tbrpc_bench_echo_throughput(size_t payload_size, int seconds,
-                                   int concurrency) {
+double tbrpc_bench_echo_qps(int seconds, int concurrency, double* p99_us_out) {
   BenchEnv env;
-  if (!env.ok) return -1;
+  if (!env.ok) return 0;
   if (concurrency < 1) concurrency = 1;
-  std::atomic<int64_t> total_bytes{0};
-  std::atomic<bool> stop{false};
-  std::vector<std::thread> workers;
-  std::string payload(payload_size, 'b');
-  for (int t = 0; t < concurrency; ++t) {
-    workers.emplace_back([&] {
-      while (!stop.load(std::memory_order_relaxed)) {
-        Controller cntl;
-        tbutil::IOBuf request, response;
-        request.append("x");
-        cntl.request_attachment().append(payload);
-        env.channel->channel.CallMethod("EchoService/Echo", &cntl, request,
-                                        &response, nullptr);
-        if (!cntl.Failed()) {
-          total_bytes.fetch_add(
-              static_cast<int64_t>(cntl.response_attachment().size()),
-              std::memory_order_relaxed);
-        }
-      }
-    });
-  }
-  const int64_t t0 = tbutil::monotonic_time_us();
-  std::this_thread::sleep_for(std::chrono::seconds(seconds));
-  stop.store(true);
-  for (auto& w : workers) w.join();
-  const double elapsed_s = (tbutil::monotonic_time_us() - t0) / 1e6;
-  return static_cast<double>(total_bytes.load()) / elapsed_s;
-}
-
-double tbrpc_bench_echo_ex(size_t payload_size, int seconds, int concurrency,
-                           int transport, int conn_type, double* qps_out,
-                           double* p50_us_out, double* p99_us_out) {
-  BenchEnv env(transport == 1, conn_type);
-  if (!env.ok) return -1;
-  if (concurrency < 1) concurrency = 1;
-  std::atomic<int64_t> total_bytes{0};
   std::atomic<int64_t> total_calls{0};
   std::atomic<bool> stop{false};
   std::mutex lat_mu;
   std::vector<int64_t> latencies;
-  std::string payload(payload_size, 'b');
+  const std::string payload(4, 'b');
   // Callers are FIBERS, the framework's native concurrency unit (the
   // reference's multi_threaded_echo benchmarks drive with bthreads the
   // same way): a parked fiber caller wakes by a queue push on an already
@@ -2120,7 +2080,6 @@ double tbrpc_bench_echo_ex(size_t payload_size, int seconds, int concurrency,
   struct CallerArg {
     BenchEnv* env;
     std::atomic<bool>* stop;
-    std::atomic<int64_t>* total_bytes;
     std::atomic<int64_t>* total_calls;
     std::mutex* lat_mu;
     std::vector<int64_t>* latencies;
@@ -2138,9 +2097,6 @@ double tbrpc_bench_echo_ex(size_t payload_size, int seconds, int concurrency,
       a->env->channel->channel.CallMethod("EchoService/Echo", &cntl,
                                           request, &response, nullptr);
       if (!cntl.Failed()) {
-        a->total_bytes->fetch_add(
-            static_cast<int64_t>(cntl.response_attachment().size()),
-            std::memory_order_relaxed);
         a->total_calls->fetch_add(1, std::memory_order_relaxed);
         local.push_back(cntl.latency_us());
       }
@@ -2152,8 +2108,8 @@ double tbrpc_bench_echo_ex(size_t payload_size, int seconds, int concurrency,
   };
   std::vector<tbthread::fiber_t> fibers(concurrency);
   for (int t = 0; t < concurrency; ++t) {
-    auto* arg = new CallerArg{&env, &stop, &total_bytes, &total_calls,
-                              &lat_mu, &latencies, &payload};
+    auto* arg = new CallerArg{&env, &stop, &total_calls, &lat_mu,
+                              &latencies, &payload};
     if (tbthread::fiber_start_background(&fibers[t], nullptr, caller, arg) !=
         0) {
       delete arg;
@@ -2167,29 +2123,13 @@ double tbrpc_bench_echo_ex(size_t payload_size, int seconds, int concurrency,
     if (f != 0) tbthread::fiber_join(f, nullptr);
   }
   const double elapsed_s = (tbutil::monotonic_time_us() - t0) / 1e6;
-  if (qps_out != nullptr) {
-    *qps_out = static_cast<double>(total_calls.load()) / elapsed_s;
-  }
-  if (p50_us_out != nullptr) *p50_us_out = 0;
-  if (p99_us_out != nullptr) *p99_us_out = 0;
-  if (!latencies.empty()) {
-    std::sort(latencies.begin(), latencies.end());
-    if (p50_us_out != nullptr) {
-      *p50_us_out = static_cast<double>(latencies[latencies.size() / 2]);
-    }
-    if (p99_us_out != nullptr) {
+  if (p99_us_out != nullptr) {
+    *p99_us_out = 0;
+    if (!latencies.empty()) {
+      std::sort(latencies.begin(), latencies.end());
       *p99_us_out = static_cast<double>(
           latencies[static_cast<size_t>(latencies.size() * 0.99)]);
     }
   }
-  return static_cast<double>(total_bytes.load()) / elapsed_s;
-}
-
-double tbrpc_bench_echo_qps(int seconds, int concurrency, double* p99_us_out) {
-  // Same fiber-caller harness as tbrpc_bench_echo_ex (both entry points
-  // must measure the SAME concurrency regime).
-  double qps = 0;
-  tbrpc_bench_echo_ex(4, seconds, concurrency, /*transport=*/0,
-                      /*conn_type=*/0, &qps, nullptr, p99_us_out);
-  return qps;
+  return static_cast<double>(total_calls.load()) / elapsed_s;
 }

@@ -559,23 +559,10 @@ int tbrpc_progressive_write(uint64_t progressive_id, const void* data,
                             size_t len);
 int tbrpc_progressive_close(uint64_t progressive_id);
 
-// ---- bench harness (loops in C so Python overhead is out of the path) ----
-// Echo round-trips of `payload_size`-byte attachments for ~`seconds`, with
-// `concurrency` concurrent callers. Returns one-way payload bytes/sec.
-double tbrpc_bench_echo_throughput(size_t payload_size, int seconds,
-                                   int concurrency);
-// Small-payload echo QPS (latency-bound): returns calls/sec; if p99_us_out
-// is non-null, stores the p99 latency in microseconds.
+// ---- echo load loop (in C so Python overhead is out of the path) ----
+// Small-payload (4 B) loopback echo over TCP for ~`seconds` with
+// `concurrency` fiber callers: returns calls/sec; if p99_us_out is
+// non-null, stores the p99 latency in microseconds.
 double tbrpc_bench_echo_qps(int seconds, int concurrency, double* p99_us_out);
-
-// Full-control bench point: echo round-trips of `payload_size`-byte
-// attachments for ~`seconds` with `concurrency` callers.
-//   transport: 0 = plain TCP loopback, 1 = tpu:// (shm ICI transport).
-//   conn_type: 0 = single shared socket, 1 = pooled, 2 = short.
-// Returns one-way payload bytes/sec; optionally stores calls/sec and the
-// p99 round-trip latency (microseconds).
-double tbrpc_bench_echo_ex(size_t payload_size, int seconds, int concurrency,
-                           int transport, int conn_type, double* qps_out,
-                           double* p50_us_out, double* p99_us_out);
 
 }  // extern "C"

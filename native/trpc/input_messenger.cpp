@@ -14,14 +14,6 @@ namespace trpc {
 
 namespace {
 
-// Upper bound on messages handed to one dispatch fiber. 1 restores the
-// reference's fiber-per-message dispatch (the bench A/B toggle); the cap
-// bounds how long a burst monopolizes one worker.
-const auto* g_dispatch_batch_max = trpc::FlagRegistry::global().DefineInt(
-    "rpc_dispatch_batch_max", 16,
-    "Max parsed messages per dispatch fiber (1 = one fiber per message)",
-    [](int64_t v) { return v >= 1 && v <= 1024; });
-
 // Doorbell-free polling mode for the batched input path: after a read
 // pass drains its fd (EAGAIN) with no handler left to run, the input
 // fiber keeps RE-POLLING the fd for this many microseconds instead of
@@ -113,8 +105,7 @@ void* BatchThunk(void* argv) {
     // writev/doorbell flush carries the whole batch's responses. Pinned
     // to the batch's own socket: a handler's nested client RPC (another
     // socket) is sent immediately, never held for this flush.
-    WriteCoalesceScope scope(arg->coalesce && response_coalescing_enabled(),
-                             arg->sock);
+    WriteCoalesceScope scope(arg->coalesce, arg->sock);
     InputMessageBase* m = arg->head;
     while (m != nullptr) {
       InputMessageBase* next = m->batch_next;
@@ -135,12 +126,6 @@ void* BatchThunk(void* argv) {
 }
 
 }  // namespace
-
-int64_t dispatch_batch_max() {
-  return g_dispatch_batch_max->load(std::memory_order_relaxed);
-}
-
-bool response_coalescing_enabled() { return dispatch_batch_max() > 1; }
 
 int64_t input_poll_us() {
   return g_input_poll_us->load(std::memory_order_relaxed);
@@ -259,12 +244,11 @@ ParseResult InputMessenger::CutInputMessage(Socket* s, int* protocol_index) {
 InputMessageBase* InputMessenger::OnNewMessages(Socket* s, int* defer_error) {
   // Keep only the newest complete message as the inline candidate; older
   // ones accumulate into a batch_next chain and go to ONE dispatch fiber
-  // per <= batch_max messages (per their own fibers when batch_max == 1).
+  // per <= kDispatchBatchMax messages.
   InputMessageBase* pending = nullptr;
   InputMessageBase* batch_head = nullptr;
   InputMessageBase* batch_tail = nullptr;
   int batch_len = 0;
-  const int64_t batch_max = dispatch_batch_max();
   auto flush_batch = [&] {
     if (batch_head != nullptr) {
       ProcessBatchInFiber(s, batch_head, batch_len);
@@ -325,7 +309,7 @@ InputMessageBase* InputMessenger::OnNewMessages(Socket* s, int* defer_error) {
       // tail path.
       if (!_server_side) s->BeginDispatch();
       if (pending != nullptr) {
-        if (batch_max > 1 && pending->dispatch_batchable) {
+        if (pending->dispatch_batchable) {
           pending->batch_next = nullptr;
           if (batch_tail == nullptr) {
             batch_head = pending;
@@ -333,7 +317,7 @@ InputMessageBase* InputMessenger::OnNewMessages(Socket* s, int* defer_error) {
             batch_tail->batch_next = pending;
           }
           batch_tail = pending;
-          if (++batch_len >= batch_max) flush_batch();
+          if (++batch_len >= kDispatchBatchMax) flush_batch();
         } else {
           // Non-batchable (large) message: release the accumulated batch
           // first so cross-message dispatch keeps parse order, then give

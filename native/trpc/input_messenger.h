@@ -6,7 +6,7 @@
 //
 // Small-RPC fast path (this repo, beyond the reference): all complete
 // messages of one read event are chained and handed to ONE dispatch fiber
-// (rpc_dispatch_batch_max) instead of one fiber_start_urgent per message —
+// (kDispatchBatchMax) instead of one fiber_start_urgent per message —
 // at 64B-echo rates the per-message spawn was the dominant cost. A
 // protocol-level failure in message k of a batch (unknown service, bad
 // payload) is answered like any other request and must never poison
@@ -21,6 +21,10 @@ namespace trpc {
 
 class Socket;
 
+// Upper bound on messages handed to one dispatch fiber: it bounds how
+// long a burst monopolizes one worker.
+constexpr int kDispatchBatchMax = 16;
+
 class InputMessenger {
  public:
   // server_side: dispatch parsed messages to process_request (vs response).
@@ -29,9 +33,9 @@ class InputMessenger {
 
   // Read everything available on `s` (until EAGAIN/EOF), cutting complete
   // messages. All but the LAST are dispatched in batches to dispatch
-  // fibers (one fiber per <= rpc_dispatch_batch_max messages; exactly the
-  // reference's fiber-per-message shape when the flag is 1); the last is
-  // RETURNED so the caller (Socket::ProcessEvent) can run it inline AFTER
+  // fibers (one fiber per <= kDispatchBatchMax batchable messages; a
+  // non-batchable one gets its own fiber, the reference's shape); the last
+  // is RETURNED so the caller (Socket::ProcessEvent) can run it inline AFTER
   // releasing the input-fiber claim — a handler that parks must not
   // head-of-line-block later requests on the connection (reference
   // input_messenger.cpp:182-223).
@@ -66,15 +70,6 @@ class InputMessenger {
 
   bool _server_side;
 };
-
-// Live value of the rpc_dispatch_batch_max flag (>= 1; 1 = the reference's
-// fiber-per-message dispatch, also the bench A/B toggle).
-int64_t dispatch_batch_max();
-// True when the small-RPC fast path should also coalesce responses
-// (dispatch_batch_max() > 1): Socket::ProcessEvent and the batch fiber
-// open a WriteCoalesceScope only under this, so one flag flips the whole
-// batched regime for interleaved A/B benching.
-bool response_coalescing_enabled();
 
 // Live value of rpc_input_poll_us (>= 0; 0 = doorbell-free input polling
 // off): how long Socket::ProcessEvent busy-polls its fd after a drained

@@ -51,7 +51,7 @@ struct InputMessageBase {
   bool dispatch_batchable = false;
   // Intrusive link for batched dispatch: the messenger chains the messages
   // of one read event and hands the whole chain to ONE dispatch fiber
-  // (rpc_dispatch_batch_max). Owned by the messenger until dispatch.
+  // (kDispatchBatchMax). Owned by the messenger until dispatch.
   InputMessageBase* batch_next = nullptr;
   virtual ~InputMessageBase() = default;
   // The ONE teardown path: protocols with pooled message objects override

@@ -10,8 +10,8 @@
 //           migrator handshakes): admitted up to the FULL concurrency gate.
 //   NORMAL  unmarked legacy traffic: full gate, no reservation.
 //   BULK    tensor pull/push: admitted only while the gate keeps
-//           `rpc_bulk_headroom_pct` percent of slots free, so a saturating
-//           bulk client can never occupy the last slots a heartbeat needs.
+//           10 percent of slots free, so a saturating bulk client can
+//           never occupy the last slots a heartbeat needs.
 //
 // The AMBIENT QoS CONTEXT is the cross-call propagation vehicle — the same
 // fiber-local (thread-local off-fiber) slot discipline as the rpcz trace

@@ -51,8 +51,8 @@ struct GlobalRpcMetrics {
   tbvar::Adder<int64_t> bytes_out;
   tbvar::Adder<int64_t> connections_accepted;
   // Overload-protection plane (server admission, server.cpp): why requests
-  // were shed, and the per-lane server latency the 10x-overload bench
-  // reads (HIGH-lane p99 must stay flat while BULK saturates).
+  // were shed, and the per-lane server latency (HIGH-lane p99 must stay
+  // flat while BULK saturates; tests/test_overload.py reads it).
   tbvar::Adder<int64_t> shed_total;     // every shed, any reason
   tbvar::Adder<int64_t> shed_bulk;      // BULK lane lost its headroom race
   tbvar::Adder<int64_t> shed_tenant;    // per-tenant quota

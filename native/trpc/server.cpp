@@ -13,7 +13,6 @@
 #include "tbutil/json.h"
 #include "tbutil/logging.h"
 #include "tbutil/time.h"
-#include "trpc/flags.h"
 #include "trpc/rpc_metrics.h"
 #include "trpc/tstd_protocol.h"
 
@@ -22,12 +21,8 @@ namespace trpc {
 // Percentage of the active concurrency gate RESERVED for HIGH/NORMAL
 // traffic: BULK requests are admitted only while that many slots stay
 // free, so a saturating tensor client can never occupy the last slots a
-// heartbeat or version poll needs. 0 disables the reservation (the
-// protection-off side of the 10x-overload bench A/B).
-static auto* g_bulk_headroom_pct = TRPC_DEFINE_FLAG(
-    rpc_bulk_headroom_pct, 10,
-    "percent of the concurrency gate reserved away from BULK-lane "
-    "requests (0 = no priority reservation)");
+// heartbeat or version poll needs.
+constexpr int64_t kBulkHeadroomPct = 10;
 
 Server::~Server() {
   Stop();
@@ -176,11 +171,9 @@ bool Server::BeginRequest(const RequestQos& qos,
   // headroom free for the control plane.
   if (admit->priority == PRIORITY_BULK && _limiter != nullptr) {
     const int32_t limit = _limiter->max_concurrency();
-    const int64_t pct =
-        g_bulk_headroom_pct->load(std::memory_order_relaxed);
-    if (limit > 0 && pct > 0) {
+    if (limit > 0) {
       const int32_t headroom = std::max<int32_t>(
-          1, static_cast<int32_t>(limit * pct / 100));
+          1, static_cast<int32_t>(limit * kBulkHeadroomPct / 100));
       if (inflight_now > limit - headroom) {
         gm.shed_bulk << 1;
         return shed(TRPC_ELIMIT, "bulk lane shed: gate headroom reserved "

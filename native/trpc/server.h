@@ -210,9 +210,8 @@ class Server {
   //   2. the per-tenant quota gate (when configured) sheds a greedy
   //      tenant's overflow BEFORE it reaches the shared gate;
   //   3. the BULK lane is admitted only while the global gate keeps
-  //      `rpc_bulk_headroom_pct` percent of slots free (HIGH/NORMAL use
-  //      the full gate), so bulk saturation can't starve the control
-  //      plane;
+  //      10 percent of slots free (HIGH/NORMAL use the full gate), so
+  //      bulk saturation can't starve the control plane;
   //   4. the configured limiter (constant/auto/timeout) has the last word.
   // On a shed, `admit->error/text` carry the answer — the text ends with
   // " (retry_after_ms=N)" computed from the server's EMA latency so
@@ -225,7 +224,7 @@ class Server {
   // latency_us: handler wall time for admitted+finished requests; -1 from
   // the shed path (never reached the limiter's accounting). The Admission
   // overload also releases the tenant gate and feeds the per-lane
-  // recorders the 10x-overload bench reads.
+  // recorders.
   void EndRequest(int64_t latency_us);
   void EndRequest(int64_t latency_us, const Admission& admit);
 

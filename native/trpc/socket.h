@@ -72,9 +72,9 @@ struct WriteRequest {
 // past the scope's end.
 class WriteCoalesceScope {
  public:
-  // enabled=false constructs an inert scope (the per-message-dispatch A/B
-  // toggle: rpc_dispatch_batch_max == 1 must reproduce the old write path
-  // exactly). `only` is the single socket this scope may adopt.
+  // enabled=false constructs an inert scope (the batch fiber's
+  // spawn-failure degrade path, whose writes take the normal Socket::Write
+  // path). `only` is the single socket this scope may adopt.
   WriteCoalesceScope(bool enabled, Socket* only);
   ~WriteCoalesceScope();
   WriteCoalesceScope(const WriteCoalesceScope&) = delete;

@@ -68,7 +68,7 @@ class SocketMap {
   // may be unconnected; callers run ConnectIfNot before writing. A cached
   // socket that has died is replaced with a fresh one. `tpu` selects the
   // tpu:// ICI transport — tpu and plain connections to one endpoint are
-  // distinct cache entries (a process may use both, e.g. A/B benches).
+  // distinct cache entries (a process may use both).
   int GetOrCreate(const tbutil::EndPoint& pt, SocketUniquePtr* out,
                   const ClientTransport& tr = {});
 

@@ -20,7 +20,6 @@
 #include "trpc/controller.h"
 #include "trpc/h2_protocol.h"
 #include "trpc/http_protocol.h"
-#include "trpc/input_messenger.h"
 #include "trpc/memcache_protocol.h"
 #include "trpc/qos.h"
 #include "trpc/redis_protocol.h"
@@ -295,13 +294,11 @@ ParseResult tstd_parse(tbutil::IOBuf* source, Socket* sock) {
   msg->process_in_place = msg->meta.msg_type >= 2;  // stream frames: ordered
   // Small-RPC fast path gates, all keyed on ONE size cutoff (the ici
   // control-channel small-message threshold, so "small" means the same
-  // thing on both halves of the transport) and on the batched regime
-  // (rpc_dispatch_batch_max > 1 — the per-message A/B setting restores
-  // the seed's dispatch behavior wholesale). Batchability is granted only
+  // thing on both halves of the transport). Batchability is granted only
   // where processing provably never parks the dispatch fiber (protocol.h):
   //   * responses — client-side resolution is a correlation lookup + a
   //     caller wake (the rare small async completion with a Python
-  //     callback can park; bounded by batch_max, and tensor-class
+  //     callback can park; bounded by the batch cap, and tensor-class
   //     responses are large, hence excluded by size anyway);
   //   * requests to inline_safe services (a declared never-parks
   //     contract) or to no service at all (the ENOSERVICE answer path);
@@ -311,8 +308,7 @@ ParseResult tstd_parse(tbutil::IOBuf* source, Socket* sock) {
   // The same single FindService feeds the inline-execution decision: a
   // small request to an inline-REGISTERED service runs right on the input
   // fiber (process_in_place), skipping the dispatch hop entirely.
-  if (sock != nullptr && response_coalescing_enabled() &&
-      body_size <= ttpu::ici_small_msg_threshold()) {
+  if (sock != nullptr && body_size <= ttpu::ici_small_msg_threshold()) {
     if (msg->meta.msg_type == 1) {
       msg->dispatch_batchable = true;
     } else if (msg->meta.msg_type == 0 && sock->server_side()) {

@@ -271,7 +271,7 @@ void PeerSegmentRegistry::OnRelease(void* ptr) {
   // creates gets id 0). The sentinel silently dropped EVERY credit owed
   // by such a peer — one leaked TX block per response until the sender's
   // pool emptied and its writer parked forever (the long-standing
-  // "all threads parked" tpu:// bench wedge).
+  // "all threads parked" tpu:// wedge).
   bool notify = false;
   {
     Registry& r = registry();

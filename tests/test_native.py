@@ -50,8 +50,8 @@ def test_native(binary):
 def test_native_lib_loads_before_jax():
     """The library's global operator new/delete serve every library loaded
     after it (libstdc++ binds to the first definition in its scope), so
-    loading it BEFORE jax must leave jax working — the order every bench
-    child, fleet member and chip_smoke.py uses. It once aborted here with
+    loading it BEFORE jax must leave jax working — the order every fleet
+    member, benchmark/run.py and chip_smoke.py use. It once aborted here with
     std::bad_alloc."""
     code = ("from brpc_tpu.runtime import native\n"
             "native.lib()\n"

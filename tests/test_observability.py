@@ -768,13 +768,23 @@ def test_python_arena_gauges_track_occupancy():
         server.stop()
 
 
-# ---- bench integration ----
+# ---- framework recorders ----
 
-def test_bench_recorder_snapshot_shape():
-    """bench.py emits framework-recorder p50/p99 next to wall-clock rows:
-    drive a little traffic, then require the snapshot to carry them."""
-    import bench
+def _vars(obs, prefix):
+    """{name: value} of the /vars lines under `prefix`."""
+    out = {}
+    for line in obs.dump_vars(prefix).splitlines():
+        name, _, value = line.partition(" : ")
+        out[name.strip()] = value.strip()
+    return out
+
+
+def test_rpc_client_recorder_vars_shape(obs):
+    """The native client recorder counts every call and exposes its
+    average, p50, p99 and max on /vars; the tensor path registers its
+    arena wait-stall counter under its documented name."""
     from brpc_tpu.runtime import native
+    from brpc_tpu.runtime.tensor import TensorArena
 
     server = native.Server()
     server.add_echo_service()
@@ -785,8 +795,15 @@ def test_bench_recorder_snapshot_shape():
             ch.call("EchoService/Echo", b"x", b"y" * 1024)
     finally:
         server.stop()
-    snap = bench.recorder_snapshot()
-    assert snap["rpc_client"]["count"] >= 20
-    for key in ("p50_us", "p99_us", "avg_us", "max_us"):
-        assert key in snap["rpc_client"]
-    assert "arena_wait_stalls" in snap
+    rpc_client = _vars(obs, "rpc_client")
+    assert int(rpc_client["rpc_client_count"]) >= 20
+    for key in ("rpc_client_latency", "rpc_client_latency_50",
+                "rpc_client_latency_99", "rpc_client_max_latency"):
+        assert int(rpc_client[key]) >= 0, key
+    arena = TensorArena(1 << 20)
+    try:
+        stalls = _vars(obs, "tensor_arena_wait_stalls")
+        assert "tensor_arena_wait_stalls" in stalls
+        assert obs.counter("tensor_arena_wait_stalls").value() >= 0
+    finally:
+        arena.close()

@@ -489,7 +489,7 @@ def test_flight_events_cover_publication_lifecycle(oneside_env):
 
 def test_input_poll_flag_roundtrip_and_echo(oneside_env):
     """The doorbell-free polling flag reloads at runtime and echoes stay
-    correct while armed (the sub-10us-regime bench row rides this)."""
+    correct while armed."""
     from brpc_tpu.runtime import native
 
     L = native.lib()

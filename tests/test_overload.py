@@ -211,8 +211,8 @@ def _var_value(metrics, name):
 def test_priority_lane_keeps_control_plane_flat(overload_env):
     """BULK echo at >10x the gate's capacity: the HIGH lane's latency
     stays at the injected service time (no queueing, no sheds) while the
-    BULK lane saturates and sheds — the tentpole's acceptance shape, in
-    miniature (bench.py overload_10x measures the full A/B)."""
+    BULK lane saturates and sheds — the overload-protection acceptance
+    shape, in miniature."""
     srv = native.Server()
     srv.add_echo_service()
     srv.set_max_concurrency(4)
@@ -258,6 +258,50 @@ def test_priority_lane_keeps_control_plane_flat(overload_env):
     # plus noise headroom, NEVER a queueing multiple of it.
     assert max(lat_ms) < 2 * 100, lat_ms
     assert bulk_stats["shed"] > bulk_stats["ok"], bulk_stats
+    srv.close()
+
+
+def test_bulk_lane_leaves_a_tenth_of_the_gate(overload_env):
+    """Gate 20: a 20-deep BULK burst is admitted up to 18 and the last 2
+    shed at once (10% of the gate, 2 slots, is kept from BULK), while a
+    HIGH call during the burst still lands in the reserved slots."""
+    srv = native.Server()
+    srv.add_echo_service()
+    srv.set_max_concurrency(20)
+    port = srv.start()
+    addr = f"127.0.0.1:{port}"
+    native.inject_latency("EchoService", 1000)
+    results = []
+    barrier = threading.Barrier(20)
+
+    def bulk():
+        ch = native.Channel(addr, timeout_ms=8000, max_retry=0)
+        barrier.wait()
+        try:
+            with native.qos(native.PRIORITY_BULK, "bulk"):
+                ch.call("EchoService/Echo", BULK_PAYLOAD)
+            results.append(("ok", None))
+        except native.RpcError as e:
+            results.append(("shed", e))
+        ch.close()
+
+    threads = [threading.Thread(target=bulk) for _ in range(20)]
+    for t in threads:
+        t.start()
+    try:
+        time.sleep(0.3)  # burst in flight (holding its 1 s injection)
+        hc = native.Channel(addr, timeout_ms=8000, max_retry=0)
+        with native.qos(native.PRIORITY_HIGH, "ctl"):
+            hc.call("EchoService/Echo", b"hb")  # raises on any shed
+        hc.close()
+    finally:
+        for t in threads:
+            t.join()
+        native.inject_latency("", 0)
+    sheds = [e for kind, e in results if kind == "shed"]
+    assert len(results) == 20 and len(sheds) == 2, results
+    for e in sheds:
+        assert e.code == 1011 and "bulk lane shed" in e.text, e.text
     srv.close()
 
 

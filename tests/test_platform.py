@@ -1,6 +1,6 @@
 """Process set-up the chip entry points share: the persistent compile
-cache's directory, the CPU platform for bench children, and the native
-build's check that native/build belongs to this checkout."""
+cache's directory and the native build's check that native/build belongs
+to this checkout."""
 
 import os
 import subprocess
@@ -33,23 +33,6 @@ def test_compile_cache_dir(env_dir):
     returned, configured = proc.stdout.split()[-2:]
     want = env_dir or os.path.join(REPO, ".jax_cache")
     assert returned == configured == want
-
-
-def test_bench_children_run_on_cpu(monkeypatch):
-    """Every bench child gets the CPU platform: the bench process owns
-    the chip, and the chip belongs to one process at a time."""
-    import bench
-
-    seen = {}
-
-    def fake_run(argv, **kw):
-        seen.update(kw)
-
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    bench._run_child([sys.executable, "-c", "pass"], timeout=1)
-    assert seen["env"]["JAX_PLATFORMS"] == "cpu"
-    assert seen["timeout"] == 1
 
 
 def test_native_build_tree_must_be_this_checkouts(tmp_path, monkeypatch):

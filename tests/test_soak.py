@@ -53,7 +53,7 @@ def test_soak_async_bursts_under_watchdog(tmp_path):
 
     # The ParameterServer lives in its own process (sharing one GIL would
     # serialize client bursts against server handlers and soak the lock,
-    # not the wire) — same shape as bench.py's param child.
+    # not the wire).
     srv = subprocess.Popen(  # tpulint: allow(py-blocking)
         [sys.executable, "-c", _SERVER_CODE % (ROOT, nbytes, n_tensors)],
         stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
