@@ -35,9 +35,9 @@ from brpc_tpu.runtime import codec as codec_mod
 from brpc_tpu.runtime import groupwire, native
 from brpc_tpu.runtime.tensor import (E_UNDECODABLE, OnesideGone, OnesideMiss,
                                      OnesideReader, OnesideWindow,
-                                     PipelineWindow, TensorArena,
-                                     TensorChannel, WireTensor,
-                                     _dequant_widen,
+                                     PipelineWindow, PullTicket,
+                                     TensorArena, TensorChannel,
+                                     WireTensor, _dequant_widen,
                                      _detach_device_put_batch,
                                      _device_put_from_view,
                                      add_tensor_service,
@@ -1268,18 +1268,51 @@ class ParameterClient:
                     "ParamService/Pull", request=self._pull_request(name),
                     device=device, note_name=name)
         except native.RpcError as e:
-            self.pacer.note(e)
-            if not (self._codec_pull_failed(e) or self._qos_failed(e)):
-                raise
-            # Renegotiated (server rolled back to a pre-codec or pre-QoS
-            # build): the retried request is byte-identical to the wire
-            # that build speaks.
-            with self._qos_bulk():
-                rest, arr = self.channel.pull_device(
-                    "ParamService/Pull", request=self._pull_request(name),
-                    device=device)
+            rest, arr = self._pull_healed(e, name, device)
         self.pacer.clear()
         return int(rest.decode()), arr
+
+    def pull_send(self, name: str) -> PullTicket:
+        """``pull``'s RPC path split at the wire, first half: pace, then
+        issue ``name``'s request (BULK-stamped, codec-marked) without
+        waiting. ``pull_finish`` completes the ticket, so a caller can
+        place one reply while the next request is in flight; a ticket
+        that will not be finished is ``cancel()``-ed. One-sided reads
+        have no request to send: they stay with ``pull``."""
+        self.pacer.pace()
+        with self._qos_bulk():
+            return self.channel.pull_send(
+                "ParamService/Pull", self._pull_request(name),
+                note_name=name)
+
+    def pull_finish(self, ticket: PullTicket, device=None):
+        """-> (version, jax.Array) for a ``pull_send`` ticket: the reply
+        wait, the H2D straight from the shared pages, and ``pull``'s
+        self-heal on failure."""
+        try:
+            rest, arr = self.channel.pull_finish(ticket, device)
+        except native.RpcError as e:
+            rest, arr = self._pull_healed(e, ticket.note_name, device)
+        self.pacer.clear()
+        return int(rest.decode()), arr
+
+    @property
+    def oneside(self) -> bool:
+        """Whether ``pull`` reads the server's published window first."""
+        return self._oneside
+
+    def _pull_healed(self, e: "native.RpcError", name: str, device):
+        """A failed Pull: feed the pacer, and if the server was rolled
+        back to a pre-codec or pre-QoS build (renegotiated), retry once
+        — the retried request is byte-identical to the wire that build
+        speaks. Anything else re-raises ``e``."""
+        self.pacer.note(e)
+        if not (self._codec_pull_failed(e) or self._qos_failed(e)):
+            raise e
+        with self._qos_bulk():
+            return self.channel.pull_device(
+                "ParamService/Pull", request=self._pull_request(name),
+                device=device)
 
     def push_grad(self, name: str, grad) -> int:
         """Send a device gradient; returns the server's new version."""

@@ -36,6 +36,24 @@ annotations, so a trace VISIBLY shows push spans inside the next layer's
 compute span), plus ``step_exposed_comm_ms`` / ``step_overlapped_comm_ms``
 recorders on /vars (samples in milliseconds, as named).
 
+The tail's pull lookahead: once every push of the step is confirmed,
+only pull nodes are left on the wire lane, and each one pays an RPC (the
+shard's D2H and staging, the transport) and then a ``device_put`` of its
+reply. From then on a pull node sends the NEXT pull's request (the
+client's ``pull_send``, ``pull``'s RPC path split at the wire with
+``pull_finish`` as its second half) before it places its own reply, so
+the shard serves pull k+1 while the client copies pull k into HBM: the
+two halves run in opposite directions and the chain pays about ``max(rpc, device_put)`` per tensor instead of
+their sum. Exactly one request goes ahead: a deeper window would queue
+each pull behind others, and every pull's issue-to-ready time would grow
+with the depth. Nothing changes before the tail: a pull the lane runs
+during backward stays synchronous, and no request is outstanding while
+the lane runs a push or a confirm, so backward sees no more concurrent
+wire work than before. Clients without ``pull_send`` (the fleet) and
+one-sided readers pull synchronously. ``step_driver_pulls_ahead`` on
+/vars counts the pulls whose request went ahead (``last_stats`` has the
+step's count).
+
 Memory: the driver holds one copy of the parameter set, not three. A
 parameter is released once its layer's backward has run (the next
 version is already on its way through the pull lane), and a gradient once
@@ -59,6 +77,17 @@ from brpc_tpu.runtime.tensor import PipelineWindow
 _metrics_cache = None
 
 
+def _set_trace(ctx) -> None:
+    """Make ``ctx`` (a ``tracing.current_trace()`` pair) this thread's
+    trace context again; (0, 0) clears it."""
+    from brpc_tpu.observability import tracing
+
+    if ctx[0] or ctx[1]:
+        tracing.set_trace(*ctx)
+    else:
+        tracing.clear_trace()
+
+
 def _trace_handoff_ctx(tid: int, sid: int, qos=None):
     """The wire-lane context factory both drivers hand to ``run_graph``:
     each lane's thread inherits the step's rpcz trace context (and,
@@ -80,10 +109,7 @@ def _trace_handoff_ctx(tid: int, sid: int, qos=None):
                 yield
         finally:
             if tid:
-                if had_t or had_s:
-                    tracing.set_trace(had_t, had_s)
-                else:
-                    tracing.clear_trace()
+                _set_trace((had_t, had_s))
 
     return wire_ctx
 
@@ -103,9 +129,104 @@ def _metrics():
             "exposed": obs.latency("step_exposed_comm_ms"),
             "overlapped": obs.latency("step_overlapped_comm_ms"),
             "steps": obs.counter("step_driver_steps"),
+            "pulls_ahead": obs.counter("step_driver_pulls_ahead"),
             "partial": obs.counter("step_driver_partial_failures"),
         }
     return _metrics_cache
+
+
+class _SentPull:
+    """One pull request in flight, with its caller's call span open."""
+
+    __slots__ = ("span", "ctx", "ticket", "err")
+
+    def __init__(self, span, ctx):
+        self.span = span  # the call_span context manager, entered
+        self.ctx = ctx    # the trace context inside it
+        self.ticket = None
+        self.err: Optional[BaseException] = None
+
+
+class _TailPulls:
+    """The tail's one-deep pull lookahead (module docstring) over the
+    client's ``pull_send``/``pull_finish``: ``pull(name, nxt)`` makes
+    sure ``name``'s request is in flight, sends ``nxt``'s, then
+    completes ``name``'s. Each pull's call span opens at its send and
+    closes at its finish, with the stages of both landing on it."""
+
+    def __init__(self, client, call_span, counter):
+        self.client = client
+        self.call_span = call_span
+        self.counter = counter
+        # The running pull's request and the one ahead of it; whatever
+        # is left here when the step ends is dropped.
+        self.pending: Dict[str, _SentPull] = {}
+        self.sent_ahead = 0
+
+    def pull(self, name: str, nxt: Optional[str]):
+        if name not in self.pending:
+            self.pending[name] = self._send(name)
+        if nxt is not None:
+            self.pending[nxt] = self._send(nxt)
+            self.sent_ahead += 1
+            self.counter.add(1)
+        return self._finish(self.pending.pop(name))
+
+    def _send(self, name: str) -> _SentPull:
+        """Open ``name``'s call span, issue its request inside it (the
+        RPC's spans parent there), and give the caller its context back.
+        A failed send is kept for ``name``'s own node to raise."""
+        from brpc_tpu.observability import tracing
+
+        outer = tracing.current_trace()
+        span = self.call_span("pull", name)
+        span.__enter__()
+        sent = _SentPull(span, tracing.current_trace())
+        try:
+            sent.ticket = self.client.pull_send(name)
+        except Exception as e:  # noqa: BLE001 — belongs to name's node
+            sent.err = e
+        finally:
+            _set_trace(outer)
+        return sent
+
+    def _finish(self, sent: _SentPull):
+        """-> (version, device array), inside the pull's own call span,
+        which closes here."""
+        from brpc_tpu.observability import tracing
+
+        outer = tracing.current_trace()
+        _set_trace(sent.ctx)
+        try:
+            if sent.err is not None:
+                raise sent.err
+            got = self.client.pull_finish(sent.ticket)
+        except BaseException as e:
+            sent.span.__exit__(type(e), e, e.__traceback__)
+            raise
+        else:
+            sent.span.__exit__(None, None, None)
+        finally:
+            _set_trace(outer)
+        return got
+
+    def drop(self) -> None:
+        """Cancel a request still ahead when the step ended without its
+        node (an abort): the reply's view is released exactly once and
+        the call span closes as cancelled, with no call row."""
+        from brpc_tpu.observability import tracing
+
+        while self.pending:
+            name, sent = self.pending.popitem()
+            if sent.ticket is not None:
+                sent.ticket.cancel()
+            err = native.RpcError(native.TRPC_ECANCELED,
+                                  f"pull of {name} sent ahead, not consumed")
+            outer = tracing.current_trace()
+            try:
+                sent.span.__exit__(type(err), err, None)
+            finally:
+                _set_trace(outer)
 
 
 class OverlappedStepDriver:
@@ -128,7 +249,19 @@ class OverlappedStepDriver:
     around each pull (``kind`` ``"pull"``) and each push submit
     (``"push"``) on the wire lane: a caller's own span or timer per call,
     which the tensor path's stage annotations (``rpc``, ``device_put``,
-    ``arena_stage``) land on.
+    ``arena_stage``) land on. A pull's span runs from its request's issue
+    to its array being ready on the device, also when the request went
+    ahead from the previous pull's node.
+
+    Pulls in the tail (every push of the step confirmed) go one request
+    ahead through a client's ``pull_send``/``pull_finish``: pull k sends pull k+1's request,
+    then places its own reply (module docstring). The depth is one and
+    not a knob: at depth one a pull's issue-to-ready time stays about
+    ``rpc + device_put``, while a deeper window queues each pull behind
+    the others. A failed request sent ahead fails its own ``pull:<name>``
+    node; one left unconsumed when the step ends (an abort) is cancelled
+    and its view released. ``last_stats["pulls_ahead"]`` and the
+    ``step_driver_pulls_ahead`` counter count the pulls that went ahead.
     """
 
     def __init__(self, client, harness, overlap: bool = True,
@@ -199,6 +332,11 @@ class OverlappedStepDriver:
         acks: Dict[str, tuple] = {}
         call_span = self.call_span or (lambda kind, name:
                                        contextlib.nullcontext())
+        tail = (_TailPulls(self.client, call_span, self._m["pulls_ahead"])
+                if hasattr(self.client, "pull_send")
+                and not getattr(self.client, "oneside", False) else None)
+        settled: set = set()  # names whose push and confirm have run out
+        started: set = set()  # names whose pull node has begun
 
         def on_push_reply(tag, payload, view):
             view.release()  # push responses carry no tensor
@@ -278,10 +416,14 @@ class OverlappedStepDriver:
                     if enc is not None:
                         enc = _staged_encode(enc)
                     grad = grads.pop(name)
-                    with call_span("push", name):
-                        win.submit("ParamService/Push", array=grad,
-                                   request=name.encode(), tag=name,
-                                   encoder=enc)
+                    try:
+                        with call_span("push", name):
+                            win.submit("ParamService/Push", array=grad,
+                                       request=name.encode(), tag=name,
+                                       encoder=enc)
+                    except BaseException:
+                        settled.add(name)  # its confirm is cancelled
+                        raise
                     push_bytes.add(int(getattr(grad, "nbytes", 0)))
                     return None
             else:
@@ -301,6 +443,7 @@ class OverlappedStepDriver:
                 # way, later pushes stay in flight. A failed drain is
                 # recorded against the tag it belongs to, so one bad
                 # push never mis-attributes its neighbours.
+                settled.add(name)
                 while (name not in step_versions
                        and name not in push_failed and win is not None):
                     if not drain_one_recording():
@@ -316,10 +459,20 @@ class OverlappedStepDriver:
                 raise err
             return fn
 
+        def next_pull() -> Optional[str]:
+            # The pull the lane runs next once only pulls are left: the
+            # lowest-index one not begun whose confirm succeeded.
+            return next((n for n in rev if n not in started
+                         and n in step_versions), None)
+
         def make_pull(name):
             def fn(done):
-                with call_span("pull", name):
-                    version, arr = self.client.pull(name)
+                started.add(name)
+                if tail is not None and len(settled) == len(names):
+                    version, arr = tail.pull(name, next_pull())
+                else:
+                    with call_span("pull", name):
+                        version, arr = self.client.pull(name)
                 self._raw[name] = arr
                 self.versions[name] = version
                 return version
@@ -370,6 +523,9 @@ class OverlappedStepDriver:
                 if win is not None:
                     win.abort()
                 raise
+            finally:
+                if tail is not None:
+                    tail.drop()
             # Late replies still count: a push whose confirm was
             # cancelled may have landed server-side — drain the window
             # so `applied` is accurate before salvage math (the wire
@@ -409,6 +565,7 @@ class OverlappedStepDriver:
             "wire_busy_ms": trace.wire_busy_s * 1e3,
             "exposed_comm_ms": exposed_ms,
             "overlapped_comm_ms": overlapped_ms,
+            "pulls_ahead": tail.sent_ahead if tail is not None else 0,
         }
         self.last_stats = stats
         self.last_trace = trace
@@ -431,7 +588,12 @@ class OverlappedStepDriver:
             # Compute- or pull-side failure: nothing ambiguous about the
             # pushes (they all confirmed or never started) — surface the
             # original cause. Not a PARTIAL-push failure, so the
-            # counter stays put (operators alert on it).
+            # counter stays put (operators alert on it). The post-mortem
+            # rides on it, as on a partial push.
+            try:
+                sf.cause.step_failure = sf
+            except Exception:  # noqa: BLE001 — exotic exception
+                pass
             return sf.cause
         self._m["partial"].add(1)
         cause = None

@@ -1056,6 +1056,24 @@ class PipelineWindow:
             self.abort()
 
 
+class PullTicket:
+    """A pull request in flight (``TensorChannel.pull_send``): when it
+    was issued, its future, and the tensor name for codec accounting."""
+
+    __slots__ = ("t0", "future", "note_name")
+
+    def __init__(self, t0: float, future: TensorFuture,
+                 note_name: Optional[str]):
+        self.t0 = t0
+        self.future = future
+        self.note_name = note_name
+
+    def cancel(self) -> None:
+        """Drop a ticket that will not be finished: the RPC is cancelled
+        and its reply's view released exactly once."""
+        self.future.close()
+
+
 class TensorChannel:
     """Client stub for tensor traffic: a ``tpu://`` channel plus a local
     arena the outbound tensors stage through."""
@@ -1193,10 +1211,37 @@ class TensorChannel:
 
         Observability: records into the tensor_pull LatencyRecorder and
         tensor_pull_bytes counter, and annotates the active rpcz span with
-        the rpc / device_put stage split."""
+        the rpc / device_put stage split.
+
+        ``pull_send`` + ``pull_finish`` are the same pull split at the
+        wire, for a caller that places one reply while the next request
+        is in flight; this one blocks in ``call_raw`` (its ``gil_return``
+        stage) instead of parking on a future."""
         t0 = time.monotonic()
         with _stage("rpc"):
             payload, view = self.call_raw(service_method, request)
+        return self._place_pull(t0, payload, view, device, note_name)
+
+    def pull_send(self, service_method: str, request: bytes = b"",
+                  note_name: Optional[str] = None) -> PullTicket:
+        """``pull_device``'s first half: issue the request without
+        waiting for it. ``pull_finish`` completes the ticket; a ticket
+        that will not be finished is ``cancel()``-ed."""
+        return PullTicket(time.monotonic(),
+                          self.call_async(service_method, request),
+                          note_name)
+
+    def pull_finish(self, ticket: PullTicket, device=None):
+        """``pull_device``'s second half over a ``pull_send`` ticket:
+        the reply wait (the ``rpc`` stage), then the same placement,
+        view release and recorders. -> (rest_of_payload, jax.Array)."""
+        with _stage("rpc"):
+            payload, view = ticket.future.result()
+        return self._place_pull(ticket.t0, payload, view, device,
+                                ticket.note_name)
+
+    def _place_pull(self, t0: float, payload: bytes, view: TensorView,
+                    device, note_name: Optional[str]):
         rest, dev, nbytes = consume_pull_reply(payload, view, device,
                                                note_name=note_name)
         m = _metrics()

@@ -296,7 +296,8 @@ def overlap_env(tmp_path_factory):
         f"{health.last_dump_path()}")
 
 
-def _fresh_pair(sizes=(24, 32, 32, 16), seed=0, codec=None, lr=0.05):
+def _fresh_pair(sizes=(24, 32, 32, 16), seed=0, codec=None, lr=0.05,
+                oneside=False):
     """(server, client, harness) over a fresh ParameterServer holding
     the harness's init params."""
     from brpc_tpu.models.tensor_service import LayeredMLP
@@ -304,9 +305,10 @@ def _fresh_pair(sizes=(24, 32, 32, 16), seed=0, codec=None, lr=0.05):
                                                ParameterServer)
 
     h = LayeredMLP(list(sizes), seed=seed)
-    ps = ParameterServer(dict(h.init_params()), lr=lr)
+    ps = ParameterServer(dict(h.init_params()), lr=lr, oneside=oneside)
     port = ps.start()
-    client = ParameterClient(f"tpu://127.0.0.1:{port}", codec=codec)
+    client = ParameterClient(f"tpu://127.0.0.1:{port}", codec=codec,
+                             oneside=oneside)
     return ps, client, h
 
 
@@ -719,5 +721,420 @@ def test_driver_releases_parameters_and_staged_gradients(overlap_env):
         assert all(v == 2 for v in driver.versions.values())
         assert "trainer_tokens" in obs.dump_vars("trainer_")
     finally:
+        client.close()
+        ps.stop()
+
+
+# ---------------------------------------------------------------------------
+# The tail's pull lookahead.
+# ---------------------------------------------------------------------------
+
+
+def _wait_for(pred, timeout_s=10.0) -> bool:
+    deadline = time.monotonic() + timeout_s
+    while not pred():
+        if time.monotonic() > deadline:
+            return False
+        time.sleep(0.002)  # tpulint: allow(py-blocking)
+    return True
+
+
+def _drain(arena, timeout_s=5.0) -> int:
+    _wait_for(lambda: arena.busy_bytes() == 0, timeout_s)
+    return arena.busy_bytes()
+
+
+class _Confirmed:
+    """A push future whose reply wait is logged as the push's confirm."""
+
+    def __init__(self, fut, log):
+        self._fut = fut
+        self._log = log
+
+    def result(self, *a, **kw):
+        try:
+            return self._fut.result(*a, **kw)
+        finally:
+            self._log.append(("confirm", None))
+
+    def __getattr__(self, attr):
+        return getattr(self._fut, attr)
+
+
+class _WireLog:
+    """The client's pull issues (``sync`` through call_raw, ``async``
+    through call_async), push issues and confirms, and each pulled
+    array's placement on the device, in the order they happened."""
+
+    def __init__(self, client, monkeypatch):
+        import brpc_tpu.runtime.tensor as tensor_mod
+
+        self.events = []
+        log = self.events
+        ch = client.channel
+        call_async, call_raw = ch.call_async, ch.call_raw
+        consume = tensor_mod.consume_pull_reply
+
+        def name_of(request):
+            return bytes(request).split(b"\x00")[0].decode()
+
+        def logged_call_async(method, request=b"", *a, **kw):
+            fut = call_async(method, request, *a, **kw)
+            if method == "ParamService/Pull":
+                log.append(("issue_async", name_of(request)))
+            elif method == "ParamService/Push":
+                log.append(("push", None))
+                return _Confirmed(fut, log)
+            return fut
+
+        def logged_call_raw(method, request, *a, **kw):
+            if method == "ParamService/Pull":
+                log.append(("issue_sync", name_of(request)))
+            return call_raw(method, request, *a, **kw)
+
+        def logged_consume(payload, view, device=None, note_name=None):
+            out = consume(payload, view, device, note_name=note_name)
+            log.append(("placed", note_name))
+            return out
+
+        monkeypatch.setattr(ch, "call_async", logged_call_async)
+        monkeypatch.setattr(ch, "call_raw", logged_call_raw)
+        monkeypatch.setattr(tensor_mod, "consume_pull_reply", logged_consume)
+
+    def index(self, kind, name):
+        return self.events.index((kind, name))
+
+    def kinds(self, kind):
+        return [n for k, n in self.events if k == kind]
+
+
+class _Gated:
+    """A LayeredMLP whose backward of a layer can first wait for a
+    condition; ``bwd_done`` is set once the bottom layer's gradient is
+    out."""
+
+    def __init__(self, h, before=None):
+        self.h = h
+        self.names = h.names
+        self.before = dict(before or {})
+        self.bwd_done = threading.Event()
+
+    def place(self, name, arr):
+        return self.h.place(name, arr)
+
+    def forward(self, params, x, y):
+        return self.h.forward(params, x, y)
+
+    def backward(self, ctx, name):
+        cond = self.before.get(name)
+        if cond is not None:
+            assert _wait_for(cond), f"bwd:{name} gate never opened"
+        g = self.h.backward(ctx, name)
+        if name == self.names[0]:
+            self.bwd_done.set()
+        return g
+
+    def loss(self, ctx):
+        return self.h.loss(ctx)
+
+
+def _lookahead_setup(mode, h, log_box):
+    """(harness, overlap, call_span) for one lookahead case:
+
+    * ``serial``: overlap off, every pull is in the tail;
+    * ``overlapped_tail``: the top layer's push waits until every
+      backward has run, so every push precedes every confirm and every
+      pull is in the tail;
+    * ``overlapped_mixed``: the second layer's backward waits until the
+      top layer's pull is placed, so that pull runs while pushes are
+      still unconfirmed — synchronously, and uncounted;
+    * ``oneside``: the client reads the server's mapped window, never
+      sent ahead."""
+    top, below = h.names[-1], h.names[-2]
+    gated = _Gated(h)
+
+    @contextlib.contextmanager
+    def call_span(kind, name):
+        if (mode == "overlapped_tail" and kind == "push" and name == top):
+            assert gated.bwd_done.wait(10), "backward never finished"
+        yield
+
+    if mode == "overlapped_mixed":
+        gated.before[below] = lambda: ("placed", top) in log_box[0].events
+    return gated, mode != "serial", call_span
+
+
+@pytest.mark.parametrize(
+    "mode", ["serial", "overlapped_tail", "overlapped_mixed", "oneside"])
+def test_tail_sends_next_pull_before_placing_current(overlap_env, mode,
+                                                     monkeypatch):
+    """In the tail (every push confirmed) pull k+1's request is issued
+    before pull k's array is placed; no pull is sent ahead while a push
+    is unconfirmed; ``pulls_ahead`` counts exactly the pulls that went
+    ahead, at most ``len(names) - 1``, on /vars as on ``last_stats``."""
+    from brpc_tpu.observability import metrics as obs
+    from brpc_tpu.runtime.step_driver import OverlappedStepDriver
+
+    ps, client, h = _fresh_pair(sizes=(24, 32, 32, 32, 16),
+                                oneside=(mode == "oneside"))
+    names, rev = h.names, list(reversed(h.names))
+    counter = obs.counter("step_driver_pulls_ahead")
+    log_box = [None]
+    harness, overlap, call_span = _lookahead_setup(mode, h, log_box)
+    try:
+        driver = OverlappedStepDriver(client, harness, overlap=overlap,
+                                      window=2, call_span=call_span)
+        driver.prime()
+        for step in range(2):
+            monkeypatch.undo()
+            log = log_box[0] = _WireLog(client, monkeypatch)
+            harness.bwd_done.clear()
+            before = counter.value()
+            driver.step(*h.data(8, seed=600 + step))
+            ahead = driver.last_stats["pulls_ahead"]
+            assert counter.value() - before == ahead
+            assert all(v == step + 1 for v in driver.versions.values())
+            if mode == "oneside":
+                assert ahead == 0 and log.kinds("issue_async") == []
+                continue
+            assert sorted(log.kinds("placed")) == sorted(names)
+            assert len(log.kinds("confirm")) == len(names)
+            last_confirm = max(i for i, (k, _n) in enumerate(log.events)
+                               if k == "confirm")
+            sent = log.kinds("issue_async")
+            # Every pull request through call_async went out after the
+            # last push confirm: nothing rides ahead of a push.
+            assert all(log.index("issue_async", n) > last_confirm
+                       for n in sent)
+            assert sorted(sent + log.kinds("issue_sync")) == sorted(names)
+            # The first tail pull sends its own request; each later one
+            # was sent from its predecessor's node, before that
+            # predecessor's array was placed.
+            assert ahead == max(0, len(sent) - 1)
+            assert 0 < ahead <= len(names) - 1
+            assert sent == [n for n in rev if n in sent]
+            for prev, nxt in zip(sent, sent[1:]):
+                assert log.index("issue_async", nxt) < log.index(
+                    "placed", prev), (prev, nxt, log.events)
+            if mode == "overlapped_mixed":
+                top = names[-1]
+                assert log.index("issue_sync", top) < last_confirm
+                assert ahead <= len(names) - 2
+            else:
+                assert ahead == len(names) - 1
+    finally:
+        monkeypatch.undo()
+        client.close()
+        ps.stop()
+
+
+def test_lookahead_trajectories_match_plain_pulls(overlap_env):
+    """Overlapped, serial and a plain loop of ``client.pull`` /
+    ``push_grad`` calls give the same losses, the same final server
+    state and the same last pulls, bit for bit."""
+    from brpc_tpu.runtime.step_driver import OverlappedStepDriver
+
+    pairs = [_fresh_pair(sizes=(24, 32, 32, 32, 16)) for _ in range(3)]
+    h = pairs[0][2]
+    steps = 3
+    try:
+        losses, drivers = {}, {}
+        for (ps, client, _h), overlap in zip(pairs[:2], (True, False)):
+            d = OverlappedStepDriver(client, h, overlap=overlap, window=2)
+            d.prime()
+            losses[overlap] = _drive(d, h, steps)
+            drivers[overlap] = d
+            assert d.last_stats["pulls_ahead"] > 0
+        plain = pairs[2][1]
+        losses["plain"] = []
+        for i in range(steps):
+            x, y = h.data(8, seed=100 + i)
+            params = {n: plain.pull(n)[1] for n in h.names}
+            grads, loss = h.grads(params, x, y)
+            for name in reversed(h.names):
+                plain.push_grad(name, grads[name])
+            losses["plain"].append(loss)
+        assert losses[True] == losses[False] == losses["plain"]
+        for name in h.names:
+            got = [client.pull(name) for _ps, client, _h in pairs]
+            assert [v for v, _ in got] == [steps] * 3
+            ref = np.asarray(got[2][1])
+            for (_v, w), d in zip(got[:2], (drivers[True],
+                                            drivers[False])):
+                assert np.array_equal(np.asarray(w), ref)
+                assert d.versions[name] == steps
+                assert np.array_equal(np.asarray(d._raw[name]), ref)
+    finally:
+        for ps, client, _h in pairs:
+            client.close()
+            ps.stop()
+
+
+@pytest.mark.parametrize("mode", ["serial", "overlapped_tail"])
+def test_failed_pull_sent_ahead_keeps_its_owner(overlap_env, mode):
+    """The server retires a tensor just before its request goes ahead:
+    the failure is its own ``pull:<name>`` node's, every other pull
+    completes, no arena range stays busy (no view leaks), and once the
+    tensor is back the next step's forward fetches it."""
+    from brpc_tpu.runtime import native
+    from brpc_tpu.runtime.param_server import ParameterClient
+    from brpc_tpu.runtime.step_driver import OverlappedStepDriver
+
+    ps, client, h = _fresh_pair(sizes=(24, 32, 32, 32, 16))
+    rev = list(reversed(h.names))
+    victim = rev[1]  # sent ahead from the first tail pull's node
+    ctl = ParameterClient(f"tpu://127.0.0.1:{ps.port}")
+    harness, overlap, base_span = _lookahead_setup(mode, h, [None])
+    armed, taken = [False], {}
+
+    @contextlib.contextmanager
+    def call_span(kind, name):
+        if armed[0] and kind == "pull" and name == rev[0]:
+            armed[0] = False
+            taken["version"], taken["stacked"] = ctl.handoff(victim)
+            ctl.retire(victim)
+        with base_span(kind, name):
+            yield
+
+    try:
+        driver = OverlappedStepDriver(client, harness, overlap=overlap,
+                                      window=2, call_span=call_span)
+        driver.prime()
+        driver.step(*h.data(8, seed=700))
+        armed[0] = True
+        harness.bwd_done.clear()
+        with pytest.raises(native.RpcError) as ei:
+            driver.step(*h.data(8, seed=701))
+        sf = ei.value.step_failure
+        assert set(sf.failed) == {f"pull:{victim}"}
+        assert sf.cancelled == []
+        assert set(driver._raw) == set(h.names) - {victim}
+        assert all(driver.versions[n] == 2 for n in h.names)
+        assert _drain(ps.arena) == 0
+        assert _drain(client.channel.arena) == 0
+        ctl.install(victim, taken["stacked"], taken["version"],
+                    commit=True)
+        harness.bwd_done.clear()
+        driver.step(*h.data(8, seed=702))
+        assert all(v == 3 for v in driver.versions.values())
+        assert set(driver._raw) == set(h.names)
+        assert _drain(ps.arena) == 0
+    finally:
+        ctl.close()
+        client.close()
+        ps.stop()
+
+
+class _Abort(BaseException):
+    """Ctrl-C's stand-in: a BaseException out of a wire node."""
+
+
+@pytest.mark.parametrize("mode", ["serial", "overlapped_tail"])
+def test_abort_cancels_the_pull_sent_ahead(overlap_env, mode):
+    """A step aborted while a request is ahead cancels it: its view is
+    released, its call span closes, and the next step's forward fetches
+    every parameter the aborted step left unpulled."""
+    from brpc_tpu.runtime.step_driver import OverlappedStepDriver
+
+    ps, client, h = _fresh_pair(sizes=(24, 32, 32, 32, 16))
+    rev = list(reversed(h.names))
+    harness, overlap, base_span = _lookahead_setup(mode, h, [None])
+    armed, opened, closed = [False], [], []
+
+    @contextlib.contextmanager
+    def call_span(kind, name):
+        if armed[0] and kind == "pull" and name == rev[2]:
+            armed[0] = False  # rev[1]'s request is ahead by now
+            raise _Abort()
+        opened.append((kind, name))
+        with base_span(kind, name):
+            try:
+                yield
+            finally:
+                closed.append((kind, name))
+
+    try:
+        driver = OverlappedStepDriver(client, harness, overlap=overlap,
+                                      window=2, call_span=call_span)
+        driver.prime()
+        armed[0] = True
+        with pytest.raises(_Abort):
+            driver.step(*h.data(8, seed=900))
+        assert ("pull", rev[1]) in opened
+        assert sorted(opened) == sorted(closed)
+        assert rev[1] not in driver._raw
+        assert _drain(ps.arena) == 0
+        harness.bwd_done.clear()
+        driver.step(*h.data(8, seed=901))
+        assert set(driver._raw) == set(h.names)
+        assert sorted(opened) == sorted(closed)
+        assert _drain(ps.arena) == 0
+    finally:
+        client.close()
+        ps.stop()
+
+
+@pytest.mark.parametrize("mode", ["serial", "overlapped_tail"])
+def test_tail_pull_rows_and_stages_stay_per_call(overlap_env, mode):
+    """Each name has one pull row per step, issue before done; traced,
+    each pull's ``rpc`` and ``device_put`` stages sit on its own
+    ``bench.pull`` span (none on a node span), a pull sent ahead opens
+    its span inside its predecessor's node, and the pull node spans
+    close in order."""
+    from brpc_tpu.observability import tracing
+    from brpc_tpu.runtime.step_driver import OverlappedStepDriver
+
+    ps, client, h = _fresh_pair(sizes=(24, 32, 32, 32, 16))
+    rev = list(reversed(h.names))
+    index = {n: i for i, n in enumerate(h.names)}
+    harness, overlap, base_span = _lookahead_setup(mode, h, [None])
+    rows = []
+
+    @contextlib.contextmanager
+    def call_span(kind, name):
+        with base_span(kind, name):
+            with tracing.trace_span(f"bench.{kind}/{index[name]}"):
+                t0 = time.monotonic()
+                yield
+                t1 = time.monotonic()
+        rows.append((kind, name, t0, t1))
+
+    tracing.rpcz_enable(True)
+    old_n = tracing.rpcz_sample_1_in_n()
+    tracing.rpcz_set_sample_1_in_n(1)
+    try:
+        driver = OverlappedStepDriver(client, harness, overlap=overlap,
+                                      window=2, call_span=call_span)
+        driver.prime()
+        for step in range(2):
+            rows.clear()
+            harness.bwd_done.clear()
+            driver.step(*h.data(8, seed=800 + step))
+            pulls = [(n, t0, t1) for k, n, t0, t1 in rows if k == "pull"]
+            assert sorted(n for n, _t0, _t1 in pulls) == sorted(h.names)
+            assert all(t0 <= t1 for _n, t0, t1 in pulls)
+            assert driver.last_stats["pulls_ahead"] == len(h.names) - 1
+        spans = tracing.dump_rpcz()
+        last = max((s for s in spans if s["service_method"] == "train_step"),
+                   key=lambda s: s["start_us"])["trace_id"]
+        by_name = {s["service_method"]: s for s in spans
+                   if s["trace_id"] == last}
+
+        def notes(span, stage):
+            return [a for a in span.get("annotations", [])
+                    if a.startswith(stage + "=")]
+
+        for name in h.names:
+            call = by_name[f"bench.pull/{index[name]}"]
+            assert len(notes(call, "device_put")) == 1, call
+            assert len(notes(call, "rpc")) == 1, call
+            assert notes(by_name[f"step/pull:{name}"], "device_put") == []
+        nodes = [by_name[f"step/pull:{n}"] for n in rev]
+        for prev, nxt, node in zip(nodes, nodes[1:], rev[1:]):
+            assert prev["end_us"] <= nxt["start_us"]
+            assert by_name[f"bench.pull/{index[node]}"]["start_us"] <= \
+                prev["end_us"]
+    finally:
+        tracing.rpcz_set_sample_1_in_n(old_n)
         client.close()
         ps.stop()

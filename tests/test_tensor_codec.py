@@ -987,6 +987,64 @@ def test_precodec_rollback_push_self_heals(codec_env):
         ps.stop()
 
 
+@pytest.mark.parametrize("split", [False, True],
+                         ids=["pull", "pull_send_finish"])
+def test_precodec_rollback_pull_self_heals(codec_env, monkeypatch, split):
+    """A negotiated pull against a server rolled back to a PRE-codec
+    build dies E_NO_SUCH (the old server reads "name\\x00int8" as an
+    unknown name). ``pull`` and ``pull_send``/``pull_finish`` heal
+    alike: a miss keeps its error while the server still advertises the
+    codec; once it stops, the pull is retried raw — paced once, not
+    twice."""
+    from brpc_tpu.runtime.native import RpcError
+    from brpc_tpu.runtime.param_server import (E_NO_SUCH, ParameterClient,
+                                               ParameterServer)
+    from brpc_tpu.runtime.tensor import PullTicket
+
+    params = _mk_params(n=1)
+    ps = ParameterServer(dict(params))
+    port = ps.start()
+    cli = ParameterClient(f"tpu://127.0.0.1:{port}", codec="int8")
+    first = "pull_finish" if split else "pull_device"
+    real_first, real_pace = getattr(cli.channel, first), cli.pacer.pace
+    fail, paced = [0], []
+
+    def precodec(*a, **k):
+        if not fail[0]:
+            return real_first(*a, **k)
+        fail[0] -= 1
+        if isinstance(a[0], PullTicket):
+            a[0].cancel()
+        raise RpcError(E_NO_SUCH, "no such parameter: w00\x00int8")
+
+    def pull():
+        if split:
+            return cli.pull_finish(cli.pull_send("w00"))
+        return cli.pull("w00")
+
+    monkeypatch.setattr(cli.channel, first, precodec)
+    monkeypatch.setattr(cli.pacer, "pace",
+                        lambda: paced.append(1) or real_pace())
+    try:
+        assert cli.negotiated_codec() == "int8"
+        fail[0] = 1
+        with pytest.raises(RpcError) as ei:
+            pull()
+        assert ei.value.code == E_NO_SUCH
+        assert cli.negotiated_codec() == "int8"
+        ps._codecs = ()
+        fail[0], paced[:] = 1, []
+        version, arr = pull()
+        assert version == 0 and paced == [1]
+        assert cli.negotiated_codec() is None
+        np.testing.assert_array_equal(np.asarray(arr),
+                                      np.asarray(params["w00"]))
+    finally:
+        monkeypatch.undo()
+        cli.close()
+        ps.stop()
+
+
 def test_push_all_partial_versions_survive(codec_env):
     """A push_all whose window dies on a per-name failure must not
     discard the versions already confirmed: gradient application is not
