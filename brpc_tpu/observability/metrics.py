@@ -199,11 +199,14 @@ def gauge(name: str, fn: Callable[[], int]) -> PassiveGauge:
 
 def trainer_series() -> Dict[str, object]:
     """The layered trainer's series (models/mla_moe.py), per completed
-    step: ``trainer_tokens`` counts its tokens; ``trainer_expert_slots``
-    takes one sample per held expert of each expert layer, the token-slots
-    routed to it; ``trainer_expert_load_permille`` one sample per expert
-    layer, the largest of those over their mean, in thousandths."""
+    step: ``trainer_tokens`` counts its tokens; ``trainer_kda_chunks`` the
+    chunks the KDA core ran, summed over layers and sequences;
+    ``trainer_expert_slots`` takes one sample per held expert of each
+    expert layer, the token-slots routed to it;
+    ``trainer_expert_load_permille`` one sample per expert layer, the
+    largest of those over their mean, in thousandths."""
     return {"tokens": counter("trainer_tokens"),
+            "kda_chunks": counter("trainer_kda_chunks"),
             "expert_slots": latency("trainer_expert_slots"),
             "expert_load": latency("trainer_expert_load_permille")}
 

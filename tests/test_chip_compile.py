@@ -105,3 +105,39 @@ def test_sharded_tensor_service_step_compiles(topo):
     per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes)
     assert per_device < 16 << 30, per_device
+
+
+# Kimi Linear's KDA layer at its cell's 16,384 tokens: the programs up to
+# and through the chunked core compile for a v5e, and each vjp's scratch
+# stays within what the chip has left beside the shard (4.82 GB), the
+# trainer's parameters (2.41 GB) and its kept layer inputs (PERF.md §4);
+# the whole layer's vjp as one program needed 7.06 GB.
+@pytest.mark.parametrize("program,limit_gb", [("in_vjp", 2.5),
+                                              ("core_vjp", 1.0)])
+def test_kimi_linear_kda_programs_compile_within_memory(one_chip, program,
+                                                        limit_gb):
+    import json
+
+    from brpc_tpu.models import mla_moe
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi_linear_48b_a3b.json")) as fh:
+        cfg = mla_moe.Config.from_hf(json.load(fh))
+    trainer = mla_moe.MlaMoeTrainer(
+        cfg, jnp.zeros((cfg.moe_layers, cfg.router_experts)))
+    _, in_vjp, _, core_vjp = trainer._kda_programs
+    s, n, d = 16384, cfg.kda_heads, cfg.kda_head_dim
+    qkvg = _spec((1, s, n, d), jnp.float32, one_chip)
+    ins = (qkvg, qkvg, qkvg, qkvg, _spec((1, s, n), jnp.float32, one_chip))
+    if program == "in_vjp":
+        p_in, _ = trainer._kda_split({
+            name[len("model.layers.0."):-len(".weight")]:
+                _spec(shape, jnp.float32, one_chip)
+            for name, shape in trainer.shapes.items()
+            if name.startswith("model.layers.0.")})
+        x = _spec((1, s, cfg.hidden), jnp.float32, one_chip)
+        compiled = in_vjp.lower(p_in, x, ins).compile()
+    else:
+        compiled = core_vjp.lower(ins, qkvg).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < limit_gb * 1e9
